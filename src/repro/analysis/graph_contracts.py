@@ -63,8 +63,7 @@ def _mutate_f64(x):
 @contextlib.contextmanager
 def _lowering_ctx(mutant: Optional[str]):
     if mutant == "f64":
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             yield
     else:
         yield
@@ -349,7 +348,11 @@ ENTRYPOINTS: Dict[str, Callable[[Optional[str]], str]] = {
 # trips the baseline diff. Collectives are zero on the single-device
 # lowering by construction.
 
-_TRAIN_DTYPES = ("pred", "s32", "u32", "f32")
+# u64: jax's partitionable threefry (the default since jax 0.5) builds its
+# counter as a u64 iota that it splits into two u32 halves
+# (``iota_2x32_shape`` in every jax.random.split). Those are a few index
+# elements per split, not tensor math; the f64 guard stays.
+_TRAIN_DTYPES = ("pred", "s32", "u32", "u64", "f32")
 _SERVE_DTYPES = ("pred", "s32", "u32", "f32")
 
 # copy ceiling note: the scan engines carry one layout copy of a class

@@ -518,6 +518,10 @@ def restore(template, directory: str, step: Optional[int] = None, *,
         if fname not in files:
             files[fname] = np.load(os.path.join(d, fname))
         arr = files[fname][meta["npz_key"]]
+        want = jax.numpy.dtype(meta["dtype"])
+        if arr.dtype != want:
+            # np.savez stores extension dtypes (bfloat16) as raw void bytes
+            arr = arr.view(want)
         if verify:
             assert zlib.crc32(arr.tobytes()) == meta["crc32"], f"corrupt leaf {key}"
         return arr

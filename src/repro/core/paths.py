@@ -1,11 +1,9 @@
-"""Version-tolerant rendering of jax key paths as "a/b/c" strings.
+"""Tree paths rendered as "a/b/c" strings.
 
-jax >= 0.5 supports ``keystr(kp, simple=True, separator="/")``; jax 0.4.x
-only accepts ``keystr(keys)``. Tree paths are the stable identifiers for
-every leaf in this codebase (sharding rules, checkpoints, tile grouping),
-so they must render identically across jax versions. ``npz_key`` /
-``npz_path`` are the matching on-disk encoding used by checkpoint
-manifests (np.savez member names cannot contain "/").
+Tree paths are the stable identifiers for every leaf in this codebase
+(sharding rules, checkpoints, tile grouping). ``npz_key`` / ``npz_path``
+are the matching on-disk encoding used by checkpoint manifests
+(np.savez member names cannot contain "/").
 """
 from __future__ import annotations
 
@@ -13,20 +11,7 @@ import jax
 
 
 def path_str(kp) -> str:
-    try:
-        return jax.tree_util.keystr(kp, simple=True, separator="/")
-    except TypeError:
-        parts = []
-        for k in kp:
-            if hasattr(k, "key"):        # DictKey / SequenceKey
-                parts.append(str(k.key))
-            elif hasattr(k, "name"):     # GetAttrKey
-                parts.append(str(k.name))
-            elif hasattr(k, "idx"):      # FlattenedIndexKey
-                parts.append(str(k.idx))
-            else:
-                parts.append(str(k).strip("[].'\""))
-        return "/".join(parts)
+    return jax.tree_util.keystr(kp, simple=True, separator="/")
 
 
 def npz_key(path: str) -> str:

@@ -63,8 +63,8 @@ def _fused_generic(w, dw, dp, cfg, key, *, bl, noise=None):
     """Fused update for any response family (jnp path; the kernels' oracle).
 
     With pre-drawn ``noise=(ubits, zeta)`` the rounding uniform is
-    ``ubits * 2**-32`` — the exact expression the Pallas kernel and the
-    jnp ref use — so this path is bit-comparable against them.
+    ``kref.uniform_from_bits(ubits)`` — the expression the Pallas kernel
+    and the jnp ref use — so this path is bit-comparable against them.
     """
     wf = w.astype(jnp.float32)
     if noise is None:
@@ -75,7 +75,7 @@ def _fused_generic(w, dw, dp, cfg, key, *, bl, noise=None):
         ubits, zeta = noise
         x = dw.astype(jnp.float32) / cfg.dw_min
         fl = jnp.floor(x)
-        u = ubits.astype(jnp.float32) * (1.0 / 4294967296.0)
+        u = kref.uniform_from_bits(ubits)
         n_q = fl + (u < x - fl).astype(jnp.float32)
     if bl:
         n_q = jnp.clip(n_q, -float(bl), float(bl))

@@ -32,9 +32,8 @@ The same train_step is used single-host and under GSPMD (the dry-run lowers
 it with sharded in/out specs; gradients reduce over the data axes before
 pulse quantization, so Assumption 3.4 applies to the global gradient).
 Passing ``mesh=`` pins the grouped update path to explicit in/out specs —
-the stack dim on the ZeRO/data axes, member dims on the model axis — via
-shard_map where available (jax >= 0.6) and with_sharding_constraint on
-jax 0.4.x (see distributed/sharding.py).
+the stack dim on the ZeRO/data axes, member dims on the model axis — and
+runs its phases under shard_map (see distributed/sharding.py).
 """
 from __future__ import annotations
 
@@ -294,6 +293,15 @@ class AnalogTrainer:
         return shd.constrain_stacked(tree, member_paths, self.mesh,
                                      prefix=prefix)
 
+    def _stacked_call(self, fn, n: int, *args, axis: int = 0):
+        """``fn(*args)`` over stacks of ``n`` tiles; with a mesh, as a
+        manual map over the stack axis (``shard_stacked_call``)."""
+        if self.mesh is None:
+            return fn(*args)
+        from repro.distributed import sharding as shd
+
+        return shd.shard_stacked_call(fn, self.mesh, n, *args, axis=axis)
+
     def _grouped_apply(self, bank: TileBank, make_vfn, key, extras=()):
         """Apply one stack-level function per scan class, in place.
 
@@ -309,9 +317,10 @@ class AnalogTrainer:
         restack concatenates). Per-group keys fold a CRC of the group's
         member-path tuple — identical between the scanned and unrolled
         engines (bit-identical results) and independent of which other
-        groups co-train. With a mesh, stacks are pinned to explicit specs:
-        shard_map over the stack axis where available (jax >= 0.6),
-        with_sharding_constraint + GSPMD otherwise (jax 0.4.x).
+        groups co-train. With a mesh, stacks are pinned to explicit specs
+        and every phase runs as a manual map over the stack axis
+        (``shard_stacked_call``), so the Pallas kernel is never left to
+        GSPMD.
 
         extras: {class-name: (C, n, ...) stacked array} pytrees of
         per-class inputs (analog gradients). Returns {class-name: vfn
@@ -353,14 +362,7 @@ class AnalogTrainer:
                         kr) + tuple(
                             self._constrain(flat(e[cname]), paths_list)
                             for e in extras)
-                res = None
-                if self.mesh is not None:
-                    from repro.distributed import sharding as shd
-
-                    res = shd.shard_stacked_call(
-                        vfn, self.mesh, flat_n, *args)
-                if res is None:
-                    res = vfn(*args)
+                res = self._stacked_call(vfn, flat_n, *args)
                 out[cname] = self._constrain(
                     jax.tree.map(
                         lambda l: l.reshape((n_c, flat_n // n_c)
@@ -376,7 +378,11 @@ class AnalogTrainer:
                 def body(carry, xs):
                     return carry, vfn(*xs)
 
-                _, res = jax.lax.scan(body, (), (cstate, kr, *ex))
+                def scanned(*xs):
+                    return jax.lax.scan(body, (), xs)[1]
+
+                res = self._stacked_call(scanned, len(paths_list[0]),
+                                         cstate, kr, *ex, axis=1)
                 out[cname] = self._constrain(res, paths_list, prefix=1)
             else:
                 results = []
@@ -389,14 +395,7 @@ class AnalogTrainer:
                                     _class_member(e[cname], ci),
                                     paths)
                                 for e in extras)
-                    res = None
-                    if self.mesh is not None:
-                        from repro.distributed import sharding as shd
-
-                        res = shd.shard_stacked_call(
-                            vfn, self.mesh, len(paths), *args)
-                    if res is None:
-                        res = vfn(*args)
+                    res = self._stacked_call(vfn, len(paths), *args)
                     results.append(self._constrain(res, paths))
                 out[cname] = _stack_rows(results)
         return out

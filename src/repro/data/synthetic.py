@@ -19,6 +19,9 @@ import numpy as np
 # bigram LM stream
 # ---------------------------------------------------------------------------
 
+# largest vocabulary for which BigramLM ranks a dense (vocab, vocab) table
+_DENSE_TABLE_VOCAB = 4096
+
 
 @dataclasses.dataclass
 class BigramLM:
@@ -28,9 +31,16 @@ class BigramLM:
 
     def __post_init__(self):
         rng = np.random.default_rng(self.seed)
-        logits = rng.gumbel(size=(self.vocab, self.vocab)) / self.concentration
-        # keep the table compact: top-8 successors per token
-        top = np.argsort(-logits, axis=1)[:, :8]
+        if self.vocab <= _DENSE_TABLE_VOCAB:
+            logits = rng.gumbel(size=(self.vocab, self.vocab)) / self.concentration
+            # keep the table compact: top-8 successors per token
+            top = np.argsort(-logits, axis=1)[:, :8]
+        else:
+            # a published vocabulary (~150k) would need a vocab^2 logit
+            # table (~180 GB); the top 8 of a row of iid Gumbels are 8
+            # uniform random successors, so draw those directly (repeats
+            # within a row are rare at this size and harmless)
+            top = rng.integers(0, self.vocab, size=(self.vocab, 8))
         self._succ = top.astype(np.int32)
 
     def batch(self, step: int, batch: int, seq_len: int) -> Dict[str, np.ndarray]:

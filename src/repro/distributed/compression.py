@@ -20,11 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 BLOCK = 256
 
 
@@ -78,7 +73,7 @@ def make_compressed_grad_fn(mesh: Mesh, axis_name: str = "data"):
         return mean, new_err
 
     def apply(local_grad, err_buf):
-        fn = _shard_map(
+        fn = jax.shard_map(
             _inner,
             mesh=mesh,
             in_specs=(P(axis_name), P(axis_name)),

@@ -28,11 +28,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.paths import path_str
 
-# jax >= 0.6 promotes shard_map to a top-level API; on 0.4.x the grouped
-# tile update falls back to with_sharding_constraint + GSPMD (see
-# shard_stacked_call).
-_SHARD_MAP = getattr(jax, "shard_map", None)
-
 
 def mesh_axis_sizes(mesh: Mesh):
     data_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -224,30 +219,44 @@ def constrain_stacked(tree, member_paths, mesh: Mesh, zero: bool = True,
     return jax.tree.map(c, tree)
 
 
-def shard_stacked_call(fn, mesh: Mesh, n: int, *args):
-    """Run ``fn(*args)`` with every argument/output's leading axis (length
-    ``n``) sharded over the data axes, as a manual map.
+# (stack size, data size) pairs already warned about by shard_stacked_call
+_UNSPLIT_WARNED: set = set()
 
-    ``fn`` must be element-local over axis 0 — true of every stacked tile
-    phase (begin_step / update vmapped over the stack): tile updates touch
-    only their own elements, so the shard_map needs no collectives and is
-    bit-identical to the global call. Requires jax >= 0.6 (top-level
-    jax.shard_map) and n divisible by the data-axes size; returns None
-    otherwise and the caller falls back to with_sharding_constraint +
-    GSPMD, which is the only path on jax 0.4.x.
+
+def shard_stacked_call(fn, mesh: Mesh, n: int, *args, axis: int = 0):
+    """Run ``fn(*args)`` as a manual map (``jax.shard_map``) over ``mesh``.
+
+    Every argument and output carries the stack axis (length ``n``) at
+    position ``axis``. ``fn`` must be element-local over that axis — true of
+    every stacked tile phase (begin_step / update vmapped over the stack):
+    tile updates touch only their own members, so the map needs no
+    collectives and is bit-identical to the global call. The stack axis is
+    split over the data axes when ``n`` divides their size. Otherwise every
+    device runs the whole stack: exact, at the price of redundant work, and
+    announced by a warning once per stack size.
+
+    The tile phases hold the Pallas pulse-update kernel, and GSPMD cannot
+    partition a Mosaic custom call, so with a mesh they always run here,
+    never under automatic partitioning.
     """
     data_axes, dsize, _, _ = mesh_axis_sizes(mesh)
-    if _SHARD_MAP is None or dsize <= 1 or n % dsize:
-        return None
-    daxes = data_axes if len(data_axes) > 1 else data_axes[0]
+    split = dsize > 1 and n % dsize == 0
+    if dsize > 1 and not split and (n, dsize) not in _UNSPLIT_WARNED:
+        _UNSPLIT_WARNED.add((n, dsize))
+        warnings.warn(
+            f"tile stack of {n} does not divide the data axes ({dsize}); "
+            "every device updates the whole stack", stacklevel=2)
+    daxes = (data_axes if len(data_axes) > 1 else data_axes[0]) \
+        if split else None
 
     def spec_of(leaf):
-        return P(daxes, *([None] * (getattr(leaf, "ndim", 1) - 1)))
+        nd = getattr(leaf, "ndim", axis + 1)
+        return P(*([None] * axis + [daxes] + [None] * (nd - axis - 1)))
 
     in_specs = jax.tree.map(spec_of, args)
     out_specs = jax.tree.map(spec_of, jax.eval_shape(fn, *args))
-    return _SHARD_MAP(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def state_shardings(state_tree, mesh: Mesh, zero_states: bool = True):

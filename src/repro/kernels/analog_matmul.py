@@ -1,4 +1,4 @@
-"""IO-quantized analog MVM Pallas kernel (TPU target, interpret-validated).
+"""IO-quantized analog MVM Pallas kernel (compiled on TPU; platform.py).
 
 Simulates a crossbar forward pass with DAC/ADC non-idealities (paper Table 7):
 ABS_MAX input scaling, 7-bit input quantization, MXU matmul, additive output
@@ -17,6 +17,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import platform
 
 DEFAULT_BLOCKS = (128, 256, 512)  # (bm, bn, bk)
 
@@ -68,7 +70,6 @@ def analog_mvm_pallas(
     out_bound: float,
     out_noise: float,
     blocks=DEFAULT_BLOCKS,
-    interpret: bool = True,
 ):
     """x: (M, K), w: (K, N), s: (M, 1) row scales, noise: (M, N) N(0,1).
 
@@ -93,16 +94,18 @@ def analog_mvm_pallas(
         out_bound=float(out_bound),
         out_noise=float(out_noise),
     )
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
-            pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        interpret=interpret,
-    )(x, w, s, noise)
+    return platform.run(
+        lambda interpret: pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+                pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
+                pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
+                pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+            interpret=interpret,
+        ),
+        x, w, s, noise)

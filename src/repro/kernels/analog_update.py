@@ -1,4 +1,4 @@
-"""Fused analog pulse-update Pallas kernel (TPU target, interpret-validated).
+"""Fused analog pulse-update Pallas kernel (compiled on TPU; platform.py).
 
 The analog update (paper eq. 2) touches W, dW, per-element device params
 (gamma, rho) and two noise streams — 6 weight-sized arrays — and is purely
@@ -19,6 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import platform
+from .ref import uniform_from_bits
 
 # Default VMEM tile: 6 f32 operands + 1 output at (256, 512) = ~3.7 MiB,
 # comfortably inside a 16 MiB VMEM budget; last dim is a multiple of 128
@@ -50,7 +53,7 @@ def _kernel(
     n_real = dw * inv_dwmin
     n_floor = jnp.floor(n_real)
     frac = n_real - n_floor
-    u = ubits_ref[...].astype(jnp.float32) * (1.0 / 4294967296.0)
+    u = uniform_from_bits(ubits_ref[...])
     n_q = n_floor + (u < frac).astype(jnp.float32)
     if bl and bl > 0:
         n_q = jnp.clip(n_q, -float(bl), float(bl))
@@ -83,7 +86,6 @@ def analog_update_pallas(
     sigma_c2c: float,
     bl: int = 0,
     block=DEFAULT_BLOCK,
-    interpret: bool = True,
 ):
     """Fused analog update on 2-D tiles or 3-D tile stacks.
 
@@ -92,6 +94,7 @@ def analog_update_pallas(
     the stack axis as the outermost grid dimension so each member streams
     through VMEM independently — no flatten/restack on the host side.
     ``ops.analog_update`` handles reshaping/padding of arbitrary trees.
+    Compiled on TPU, interpreted on any other platform (``platform.run``).
     """
     assert w.ndim in (2, 3), "kernel operates on 2-D tiles or 3-D stacks"
     m, n = w.shape[-2:]
@@ -113,11 +116,13 @@ def analog_update_pallas(
     else:
         grid = (w.shape[0], m // bm, n // bn)
         spec = pl.BlockSpec((1, bm, bn), lambda k, i, j: (k, i, j))
-    return pl.pallas_call(
-        kern,
-        out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
-        grid=grid,
-        in_specs=[spec] * 6,
-        out_specs=spec,
-        interpret=interpret,
-    )(w, dw, gamma, rho, ubits, zeta)
+    return platform.run(
+        lambda interpret: pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
+            grid=grid,
+            in_specs=[spec] * 6,
+            out_specs=spec,
+            interpret=interpret,
+        ),
+        w, dw, gamma, rho, ubits, zeta)

@@ -1,13 +1,15 @@
 """jit-ready wrappers around the Pallas kernels with pure-jnp fallbacks.
 
-Dispatch policy: on TPU backends the fused Pallas kernels run compiled; on
-CPU (this container) the default is the jnp reference path (the Pallas
-interpreter executes block-by-block in Python and is only meant for
-correctness tests). Both paths consume *identical* random bits so they are
-bit-comparable: tests assert allclose between backends for the same key.
+Dispatch policy: on a TPU backend the fused Pallas kernels run, compiled
+(``platform.run``); on any other backend the default is the jnp reference
+path (the Pallas interpreter executes block-by-block in Python and is only
+meant for correctness tests). Both paths consume *identical* random bits so
+they are bit-comparable: tests assert allclose between backends for the
+same key.
 
 All wrappers accept arbitrary-rank inputs; internally tensors are viewed as
-2-D and zero-padded to kernel block multiples.
+2-D (or kept as 3-D tile stacks) and tiled by blocks that divide them where
+such blocks exist, zero-padded to block multiples otherwise.
 """
 from __future__ import annotations
 
@@ -38,6 +40,24 @@ def backend() -> str:
     if _BACKEND is not None:
         return _BACKEND
     return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def _fit(dim: int, target: int, align: int) -> int:
+    """Block length for one array dim: the whole dim when it fits in
+    ``target``, else the largest multiple of ``align`` up to ``target``
+    that divides it (no padding copy), else ``target`` (ops pad)."""
+    if dim <= target:
+        return dim
+    for b in range(target - target % align, 0, -align):
+        if dim % b == 0:
+            return b
+    return target
+
+
+def _update_block(m: int, n: int):
+    # sublane blocks in multiples of 16 (bf16 packs two rows per sublane),
+    # lane blocks in multiples of 128
+    return _fit(m, UPD_BLOCK[0], 16), _fit(n, UPD_BLOCK[1], 128)
 
 
 def _pad2d(x, bm, bn, fill=0.0):
@@ -86,7 +106,6 @@ def analog_update(
     tau_max: float,
     sigma_c2c: float,
     bl: int = 0,
-    interpret: bool = True,
     rng: str = "threefry",
     noise=None,
 ):
@@ -130,19 +149,17 @@ def analog_update(
         # Tile-stack fast path: keep the member axis as the outermost kernel
         # grid dimension instead of flattening members into one 2-D view.
         m, n = shape[1:]
-        bm = min(UPD_BLOCK[0], m)
-        bn = min(UPD_BLOCK[1], n)
+        bm, bn = _update_block(m, n)
         pad3 = lambda x, fill=0.0: _pad3d(x, bm, bn, fill=fill)
         out = analog_update_pallas(
             pad3(w), pad3(dw), pad3(gamma, fill=1.0), pad3(rho),
             pad3(ubits, fill=jnp.uint32(1 << 31)), pad3(zeta),
-            interpret=interpret, **kwargs,
+            block=(bm, bn), **kwargs,
         )
         return out[:, :m, :n]
     w2 = _view2d(w)
     m, n = w2.shape
-    bm = min(UPD_BLOCK[0], m)
-    bn = min(UPD_BLOCK[1], n)
+    bm, bn = _update_block(m, n)
     w2 = _pad2d(w2, bm, bn)
     dw2 = _pad2d(_view2d(dw), bm, bn)
     g2 = _pad2d(_view2d(gamma), bm, bn, fill=1.0)
@@ -153,7 +170,7 @@ def analog_update(
     u2 = _pad2d(_view2d(ubits), bm, bn, fill=jnp.uint32(1 << 31))
     z2 = _pad2d(_view2d(zeta), bm, bn)
     out = analog_update_pallas(
-        w2, dw2, g2, r2, u2, z2, interpret=interpret, **kwargs
+        w2, dw2, g2, r2, u2, z2, block=(bm, bn), **kwargs
     )
     return out[:m, :n].reshape(shape)
 
@@ -173,7 +190,6 @@ def analog_mvm(
     out_res: float,
     out_bound: float,
     out_noise: float,
-    interpret: bool = True,
 ):
     """IO-quantized crossbar forward: x (..., K) @ w (K, N)."""
     batch_shape = x.shape[:-1]
@@ -200,7 +216,7 @@ def analog_mvm(
         # zero-padded into the kernel grid
         noise = _pad2d(jax.random.normal(key, (m, n), dtype=jnp.float32),
                        xp.shape[0], wp.shape[1])
-        out = analog_mvm_pallas(xp, wp, sp, noise, interpret=interpret, **kwargs)
+        out = analog_mvm_pallas(xp, wp, sp, noise, **kwargs)
         out = out[:m, :n].astype(x.dtype)
     else:
         noise = jax.random.normal(key, (m, n), dtype=jnp.float32)
@@ -222,7 +238,6 @@ def sp_filter(
     eta: float,
     tau_min: float,
     tau_max: float,
-    interpret: bool = True,
 ):
     """EMA tracking update (12) + telemetry. Returns (q_new, gp_sq, err_sq)."""
     shape = q.shape
@@ -237,7 +252,6 @@ def sp_filter(
     if backend() == "pallas":
         q_new, gp, err = sp_filter_pallas(
             q2, p2, g2, r2, eta=eta, tau_min=tau_min, tau_max=tau_max,
-            interpret=interpret,
         )
         # padded gamma=1, rho=0 regions contribute 0 to gp but (q-w_sp)^2 = 0
         # there as well since q=p=0 and w_sp=0.

@@ -49,6 +49,18 @@ def response_fg(w, gamma, rho, tau_min, tau_max):
 # ---------------------------------------------------------------------------
 
 
+def uniform_from_bits(ubits):
+    """uint32 random bits -> f32 uniform in [0, 1) from their top 24 bits.
+
+    The one expression behind every stochastic-rounding draw: the Pallas
+    kernel, this oracle and ``core.pulse`` all call it, so they stay
+    bit-comparable. It goes through int32 because the TPU kernel compiler
+    has no uint32 -> f32 cast; 24 bits convert to f32 exactly.
+    """
+    top = jax.lax.shift_right_logical(ubits, jnp.uint32(8))
+    return top.astype(jnp.int32).astype(jnp.float32) * (1.0 / 16777216.0)
+
+
 def analog_update_ref(
     w,
     dw,
@@ -87,7 +99,7 @@ def analog_update_ref(
     n_real = dwf / dw_min
     n_floor = jnp.floor(n_real)
     frac = n_real - n_floor
-    u = ubits.astype(jnp.float32) * (1.0 / 4294967296.0)  # [0,1)
+    u = uniform_from_bits(ubits)
     n_q = n_floor + (u < frac).astype(jnp.float32)
     if bl and bl > 0:
         n_q = jnp.clip(n_q, -float(bl), float(bl))

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import platform
+
 DEFAULT_BLOCK = (256, 512)
 
 
@@ -63,7 +65,6 @@ def sp_filter_pallas(
     tau_min: float,
     tau_max: float,
     block=DEFAULT_BLOCK,
-    interpret: bool = True,
 ):
     """Returns (q_new, gp_sq_sum, err_sq_sum). 2-D inputs, identical shapes."""
     m, n = q.shape
@@ -77,16 +78,18 @@ def sp_filter_pallas(
     )
     spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
     scalar_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
-    q_new, gp_parts, err_parts = pl.pallas_call(
-        kern,
-        out_shape=(
-            jax.ShapeDtypeStruct((m, n), q.dtype),
-            jax.ShapeDtypeStruct((gm, gn), jnp.float32),
-            jax.ShapeDtypeStruct((gm, gn), jnp.float32),
+    q_new, gp_parts, err_parts = platform.run(
+        lambda interpret: pl.pallas_call(
+            kern,
+            out_shape=(
+                jax.ShapeDtypeStruct((m, n), q.dtype),
+                jax.ShapeDtypeStruct((gm, gn), jnp.float32),
+                jax.ShapeDtypeStruct((gm, gn), jnp.float32),
+            ),
+            grid=(gm, gn),
+            in_specs=[spec] * 4,
+            out_specs=(spec, scalar_spec, scalar_spec),
+            interpret=interpret,
         ),
-        grid=(gm, gn),
-        in_specs=[spec] * 4,
-        out_specs=(spec, scalar_spec, scalar_spec),
-        interpret=interpret,
-    )(q, p, gamma, rho)
+        q, p, gamma, rho)
     return q_new, jnp.sum(gp_parts), jnp.sum(err_parts)

@@ -7,17 +7,12 @@ device count via XLA_FLAGS before any jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
 
-    def _mesh(shape, axes):
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # jax 0.4.x: all axes are implicitly Auto
-
-    def _mesh(shape, axes):
-        return jax.make_mesh(tuple(shape), tuple(axes))
+def _mesh(shape, axes):
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -37,6 +37,7 @@ import numpy as np
 from repro.configs import ARCHS, get_config
 from repro.configs.serving import serve_defaults
 from repro.data import BigramLM
+from repro.launch import compile_cache
 from repro.models.lm import LM
 from repro.serving import (EngineConfig, FeedBuilder, ServeEngine,
                            ServeRequest, Telemetry, load_effective_params,
@@ -137,7 +138,10 @@ def run_fixed(model: LM, params, workload: List[ServeRequest], batch: int,
     return results
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> dict:
+    """Serve; returns ``{"results", "summary"}``: the generated tokens by
+    request id and the run summary (``generated_tokens``, ``wall_s``,
+    ``tokens_per_s``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true")
@@ -188,6 +192,7 @@ def main(argv=None) -> None:
                     help="write {request_id: tokens} JSON (regression tests)")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = LM(cfg)
     age_s = parse_age(args.age)
@@ -271,6 +276,7 @@ def main(argv=None) -> None:
                       f, sort_keys=True)
     first = workload[0].request_id
     print(f"[serve] {first} first tokens: {np.asarray(results[first])[:12]}")
+    return {"results": results, "summary": summary}
 
 
 if __name__ == "__main__":

@@ -1,10 +1,12 @@
 """Training driver CLI: analog LM training with checkpoint/restart, fault
 tolerance and the full data pipeline.
 
-On this CPU container it runs reduced configs end-to-end (see
-examples/lm_analog_training.py); on a real fleet the same driver runs the
-full configs — the mesh factory, sharding rules and train_step are exactly
-the ones the multi-pod dry-run lowers.
+With ``--smoke`` it runs reduced configs end to end on a CPU (see
+examples/lm_analog_training.py); without it, the published widths, as
+``chip_smoke.py`` runs qwen2-0.5b on one TPU v5e. ``--data-parallel`` /
+``--model-parallel`` lay a mesh over the host's devices: state and batches
+are placed by the sharding rules and the tile updates run as a manual map
+over the tile stacks (ZeRO over the stack axis).
 
 ``--algorithm`` takes either a single algorithm name (one policy on every
 analog leaf) or a comma-separated mixed plan of ``pattern=algorithm`` rules
@@ -40,6 +42,7 @@ from repro.checkpoint import ckpt
 from repro.data import BigramLM, Prefetcher
 from repro.distributed import sharding
 from repro.distributed.fault import PreemptionHandler, StragglerMonitor
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.common import set_shard_rules
 from repro.models.lm import LM
@@ -95,7 +98,24 @@ def ckpt_extra(trainer, state) -> dict:
                                for p, v in sig_fn(eff).items()}}
 
 
-def main(argv=None) -> None:
+def _tile_bytes(tiles) -> dict:
+    """Tile-state bytes in total and on the fullest device."""
+    leaves = jax.tree.leaves(tiles)
+    per_dev: dict = {}
+    for leaf in leaves:
+        for shard in leaf.addressable_shards:
+            per_dev[shard.device] = per_dev.get(shard.device, 0) \
+                + shard.data.nbytes
+    return {"total": sum(l.nbytes for l in leaves),
+            "per_device": max(per_dev.values())}
+
+
+def main(argv=None) -> dict:
+    """Train; returns ``{"compile_s", "history", "step", "tile_bytes"}``:
+    the seconds the step took to compile, the logged steps' metrics (with
+    ``step_s``, wall seconds per step since the previous log line), the
+    compiled step, and the tile-state bytes in total and on the fullest
+    device."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
@@ -112,10 +132,12 @@ def main(argv=None) -> None:
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = LM(cfg)
     mesh = make_host_mesh(args.data_parallel, args.model_parallel)
     set_shard_rules(sharding.logical_rules(mesh))
+    sharded = mesh.size > 1
 
     plan = make_plan(args.algorithm, args.smoke)
     tcfg = TrainerConfig(
@@ -124,43 +146,64 @@ def main(argv=None) -> None:
                                 total_steps=args.steps, warmup_steps=min(20, args.steps // 5)),
     )
     trainer = AnalogTrainer(model.loss, tcfg, plan=plan,
-                            mesh=mesh if mesh.size > 1 else None)
+                            mesh=mesh if sharded else None)
 
     key = jax.random.PRNGKey(0)
     params = model.init(key)
     print(f"[train] {trainer.describe_plan(params)}", flush=True)
     state = trainer.init(jax.random.PRNGKey(1), params)
+    del params
+    # on a mesh, state and batches are placed with the sharding rules and
+    # the step is compiled for exactly that layout (state round-trips)
+    state_sh = sharding.state_shardings(state, mesh) if sharded else None
+    if sharded:
+        state = jax.device_put(state, state_sh)
 
     start_step = 0
     if args.ckpt_dir and ckpt.latest_step(args.ckpt_dir) is not None:
-        state = ckpt.restore(state, args.ckpt_dir)
+        state = ckpt.restore(state, args.ckpt_dir, shardings=state_sh)
         start_step = int(np.asarray(state["step"]))
         print(f"[train] restored checkpoint at step {start_step}")
 
     data = BigramLM(vocab=cfg.vocab, seed=7)
+    batch_sh = sharding.batch_shardings(
+        data.batch(0, args.batch, args.seq), mesh) if sharded else None
     prefetch = Prefetcher(
         lambda s: data.batch(s, args.batch, args.seq), start_step=start_step)
 
-    step_fn = trainer.jit_step()
+    jit_kwargs = ({"in_shardings": (state_sh, batch_sh),
+                   "out_shardings": (state_sh, None)} if sharded else {})
+    it = iter(prefetch)
+    first = jax.device_put(next(it), batch_sh)
+    t0 = time.perf_counter()
+    step_fn = trainer.jit_step(**jit_kwargs).lower(state, first).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"[train] compiled step in {compile_s:.1f}s", flush=True)
+
     preempt = PreemptionHandler()
     monitor = StragglerMonitor()
     history = []
     pending = None
-
-    it = iter(prefetch)
+    t_log, s_log = time.perf_counter(), start_step
+    batch = first
     for step in range(start_step, args.steps):
-        batch = {k: jnp.asarray(v) for k, v in next(it).items()}
+        if step > start_step:
+            batch = jax.device_put(next(it), batch_sh)
         monitor.start()
         state, metrics = step_fn(state, batch)
         straggler = monitor.stop()
         if step % args.log_every == 0 or step == args.steps - 1:
             m = {k: float(v) for k, v in metrics.items()}
+            now = time.perf_counter()
             m["step"] = step
+            m["step_s"] = (now - t_log) / (step + 1 - s_log)
             m["straggler"] = bool(straggler)
+            t_log, s_log = now, step + 1
             history.append(m)
             print(f"[train] step={step} loss={m['loss']:.4f} "
                   f"acc={m.get('accuracy', 0):.3f} "
-                  f"sp_err={m.get('tile/sp_err', -1):.4f} ema_s={monitor.ema:.3f}",
+                  f"sp_err={m.get('tile/sp_err', -1):.4f} "
+                  f"step_s={m['step_s']:.3f} ema_s={monitor.ema:.3f}",
                   flush=True)
         if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
             pending = ckpt.save(state, args.ckpt_dir, step + 1,
@@ -182,6 +225,8 @@ def main(argv=None) -> None:
         with open(args.metrics_out, "w") as f:
             json.dump(history, f, indent=2)
     print(f"[train] done; stragglers flagged: {monitor.flagged}")
+    return {"compile_s": compile_s, "history": history, "step": step_fn,
+            "tile_bytes": _tile_bytes(state["tiles"])}
 
 
 if __name__ == "__main__":

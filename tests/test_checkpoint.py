@@ -38,6 +38,19 @@ def test_roundtrip(tmp_path):
     assert restored["nested"]["c"] is None
 
 
+def test_bfloat16_roundtrip(tmp_path):
+    """bf16 leaves (the full-width tile state) come back as bf16, bit for
+    bit: np.savez writes them as raw 2-byte voids."""
+    t = {"w": jax.random.normal(jax.random.PRNGKey(1), (8, 24)
+                                ).astype(jnp.bfloat16)}
+    ckpt.save(t, str(tmp_path), step=1)
+    restored = ckpt.restore({"w": jnp.zeros((8, 24), jnp.bfloat16)},
+                            str(tmp_path), verify=True)
+    assert restored["w"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(t["w"], np.float32),
+                                  np.asarray(restored["w"], np.float32))
+
+
 def test_async_save_and_latest(tmp_path):
     t = _tree()
     th = ckpt.save(t, str(tmp_path), step=1, asynchronous=True)

@@ -252,6 +252,25 @@ print("SHARDED_BANK_OK", per_dev, total)
     assert "SHARDED_BANK_OK" in out
 
 
+def test_train_main_2x2_keeps_tile_state_sharded_subprocess():
+    """``launch.train.main --data-parallel 2 --model-parallel 2`` places the
+    state by the sharding rules and compiles the step for that layout: after
+    training, the tile state is still spread over the four devices (the
+    fullest holds at most half of it) and every W stack is sharded."""
+    out = _run_sub("""
+import jax, numpy as np
+from repro.launch import train
+r = train.main(["--arch", "qwen2-0.5b", "--smoke", "--steps", "2",
+                "--batch", "4", "--seq", "16", "--log-every", "1",
+                "--data-parallel", "2", "--model-parallel", "2"])
+tb = r["tile_bytes"]
+assert tb["per_device"] <= tb["total"] / 2 + 4096, tb
+assert all(np.isfinite(h["loss"]) for h in r["history"]), r["history"]
+print("TRAIN_2X2_OK", tb)
+""", devices=4)
+    assert "TRAIN_2X2_OK" in out
+
+
 def test_elastic_restore_subprocess():
     """Checkpoint saved on one mesh restores onto a different mesh shape."""
     out = _run_sub("""
