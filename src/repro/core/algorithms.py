@@ -65,26 +65,27 @@ def _dev(st: TileState, which: str, cfg: TileConfig, shape):
 def _base_metrics(cfg: TileConfig, st: TileState, dw_p=None, dw_w=None) -> Metrics:
     # each diagnostic below is an extra full pass + reduction over the tile;
     # cfg.metrics trades them away at LM scale ('pulses' / 'none')
-    if cfg.metrics == "none":
-        return {}
-    m: Metrics = {}
-    pulses = jnp.zeros((), jnp.float32)
-    if dw_p is not None:
-        pulses = pulses + expected_pulses(dw_p, cfg.device_p.dw_min, cfg.bl)
-    if dw_w is not None:
-        pulses = pulses + expected_pulses(dw_w, cfg.device_w.dw_min, cfg.bl)
-    m["pulses"] = pulses
-    has_dev_p = st.get("dev_p") is not None or st.get("seed_p") is not None
-    if cfg.metrics == "pulses":
+    with jax.named_scope("tile_metrics"):
+        if cfg.metrics == "none":
+            return {}
+        m: Metrics = {}
+        pulses = jnp.zeros((), jnp.float32)
+        if dw_p is not None:
+            pulses = pulses + expected_pulses(dw_p, cfg.device_p.dw_min, cfg.bl)
+        if dw_w is not None:
+            pulses = pulses + expected_pulses(dw_w, cfg.device_w.dw_min, cfg.bl)
+        m["pulses"] = pulses
+        has_dev_p = st.get("dev_p") is not None or st.get("seed_p") is not None
+        if cfg.metrics == "pulses":
+            return m
+        if st.get("P") is not None and has_dev_p:
+            dev_p = _dev(st, "p", cfg, st["P"].shape)
+            _, g = fg(st["P"].astype(jnp.float32), dev_p, cfg.device_p)
+            m["gp_sq"] = jnp.mean(g * g)
+            if st.get("Qd") is not None:
+                sp = symmetric_point(dev_p, cfg.device_p)
+                m["sp_err"] = jnp.mean((st["Qd"].astype(jnp.float32) - sp) ** 2)
         return m
-    if st.get("P") is not None and has_dev_p:
-        dev_p = _dev(st, "p", cfg, st["P"].shape)
-        _, g = fg(st["P"].astype(jnp.float32), dev_p, cfg.device_p)
-        m["gp_sq"] = jnp.mean(g * g)
-        if st.get("Qd") is not None:
-            sp = symmetric_point(dev_p, cfg.device_p)
-            m["sp_err"] = jnp.mean((st["Qd"].astype(jnp.float32) - sp) ** 2)
-    return m
 
 
 def _grad_to_analog(st: TileState, grad, cfg: TileConfig):
@@ -163,22 +164,27 @@ def update(
 ) -> Tuple[TileState, Metrics]:
     a = cfg.algorithm
     st = TileState(st)
-    g = _grad_to_analog(st, grad, cfg)
-    kp, kw, kq = jax.random.split(key, 3)
-    alpha = lr * cfg.lr_p
-    beta = lr * cfg.lr_w
-    dev_w = _dev(st, "w", cfg, st["W"].shape)
-    dev_p = _dev(st, "p", cfg, st["W"].shape) if (
-        st.get("dev_p") is not None or st.get("seed_p") is not None) else None
+    # the kernel's inputs: analog-space gradient, keys and device
+    # parameters (regenerated from the tile seed when not stored)
+    with jax.named_scope("pulse_operands"):
+        g = _grad_to_analog(st, grad, cfg)
+        kp, kw, kq = jax.random.split(key, 3)
+        alpha = lr * cfg.lr_p
+        beta = lr * cfg.lr_w
+        dev_w = _dev(st, "w", cfg, st["W"].shape)
+        dev_p = _dev(st, "p", cfg, st["W"].shape) if (
+            st.get("dev_p") is not None or st.get("seed_p") is not None) else None
 
     if a == "sgd":
-        dw = -beta * g
+        with jax.named_scope("pulse_operands"):
+            dw = -beta * g
         st["W"] = _au(st["W"], dw, dev_w, cfg.device_w, kw, cfg)
         metrics = _base_metrics(cfg, st, dw_w=dw)
 
     elif a in ("ttv1", "ttv2", "agad"):
         c = st["c"] if a == "agad" else jnp.ones((), jnp.float32)
-        dp = -alpha * c * g
+        with jax.named_scope("pulse_operands"):
+            dp = -alpha * c * g
         st["P"] = _au(st["P"], dp, dev_p, cfg.device_p, kp, cfg)
         do_transfer = (st["t"] % cfg.transfer_every) == 0
         read = st["P"].astype(jnp.float32)  # analog readout of the fast array
@@ -207,12 +213,14 @@ def update(
     elif a in ("residual", "rider", "erider"):
         c = st["c"] if a == "erider" else jnp.ones((), jnp.float32)
         # (11a)/(18a): P <- P - alpha c grad  (asymmetric pulse update)
-        dp = -alpha * c * g
+        with jax.named_scope("pulse_operands"):
+            dp = -alpha * c * g
         st["P"] = _au(st["P"], dp, dev_p, cfg.device_p, kp, cfg)
         p_new = st["P"].astype(jnp.float32)
         # (11b)/(18b): W <- W + beta c (P_{k+1} - Q_k)
         q_ref = st["Qt"] if a == "erider" else st["Qd"]
-        dw = beta * c * (p_new - q_ref.astype(jnp.float32))
+        with jax.named_scope("pulse_operands"):
+            dw = beta * c * (p_new - q_ref.astype(jnp.float32))
         if cfg.buffered_transfer:
             # digital forget-buffer: emit only whole-pulse increments
             thr = cfg.threshold * cfg.device_w.dw_min
@@ -226,7 +234,8 @@ def update(
                         + cfg.eta * p_new).astype(st["Qd"].dtype)
         metrics = _base_metrics(cfg, st, dw_p=dp, dw_w=dw)
         if a == "erider" and cfg.metrics != "none":
-            metrics["prog_events"] = st["prog"].astype(jnp.float32)
+            with jax.named_scope("tile_metrics"):
+                metrics["prog_events"] = st["prog"].astype(jnp.float32)
 
     else:
         raise ValueError(a)
@@ -289,7 +298,8 @@ def update_batched(
         )(st[f"seed_{which}"])
 
     def au(x, dx, dev, dcfg, kraw):
-        noise = _hash_noise_batched(kraw, member)
+        with jax.named_scope("pulse_operands"):
+            noise = _hash_noise_batched(kraw, member)
         return analog_update(x, dx, dev, dcfg, None, bl=cfg.bl,
                              mode=cfg.pulse_mode, noise=noise)
 
@@ -300,50 +310,54 @@ def update_batched(
         return jnp.sum(n, axis=axes)
 
     def base_metrics(dw_p=None, dw_w=None) -> Metrics:
-        if cfg.metrics == "none":
-            return {}
-        m: Metrics = {}
-        pulses = jnp.zeros(st["scale"].shape, jnp.float32)
-        if dw_p is not None:
-            pulses = pulses + pulses_of(dw_p, cfg.device_p.dw_min)
-        if dw_w is not None:
-            pulses = pulses + pulses_of(dw_w, cfg.device_w.dw_min)
-        m["pulses"] = pulses
-        has_dev_p = st.get("dev_p") is not None or st.get("seed_p") is not None
-        if cfg.metrics == "pulses":
+        with jax.named_scope("tile_metrics"):
+            if cfg.metrics == "none":
+                return {}
+            m: Metrics = {}
+            pulses = jnp.zeros(st["scale"].shape, jnp.float32)
+            if dw_p is not None:
+                pulses = pulses + pulses_of(dw_p, cfg.device_p.dw_min)
+            if dw_w is not None:
+                pulses = pulses + pulses_of(dw_w, cfg.device_w.dw_min)
+            m["pulses"] = pulses
+            has_dev_p = st.get("dev_p") is not None or st.get("seed_p") is not None
+            if cfg.metrics == "pulses":
+                return m
+            if st.get("P") is not None and has_dev_p:
+                dev_p = dev_of("p")
+                _, gg = fg(st["P"].astype(jnp.float32), dev_p, cfg.device_p)
+                m["gp_sq"] = jnp.mean(gg * gg, axis=axes)
+                if st.get("Qd") is not None:
+                    sp = symmetric_point(dev_p, cfg.device_p)
+                    m["sp_err"] = jnp.mean(
+                        (st["Qd"].astype(jnp.float32) - sp) ** 2, axis=axes)
             return m
-        if st.get("P") is not None and has_dev_p:
-            dev_p = dev_of("p")
-            _, gg = fg(st["P"].astype(jnp.float32), dev_p, cfg.device_p)
-            m["gp_sq"] = jnp.mean(gg * gg, axis=axes)
-            if st.get("Qd") is not None:
-                sp = symmetric_point(dev_p, cfg.device_p)
-                m["sp_err"] = jnp.mean(
-                    (st["Qd"].astype(jnp.float32) - sp) ** 2, axis=axes)
-        return m
 
-    g = grad.astype(jnp.float32) * bc(st["scale"])
-    if cfg.grad_norm == "absmean":
-        g = (g / (jnp.mean(jnp.abs(g), axis=axes, keepdims=True) + 1e-12)
-             * cfg.device_p.dw_min)
-    # per-tile kp/kw key chain, identical to update()'s split(key, 3)
-    ks = jax.vmap(lambda kr: jax.random.key_data(
-        jax.random.split(jax.random.wrap_key_data(kr), 3)))(keys_raw)
-    kp, kw = ks[:, 0], ks[:, 1]
-    alpha = lr * cfg.lr_p
-    beta = lr * cfg.lr_w
-    dev_w = dev_of("w")
-    dev_p = dev_of("p") if (st.get("dev_p") is not None
-                            or st.get("seed_p") is not None) else None
+    with jax.named_scope("pulse_operands"):
+        g = grad.astype(jnp.float32) * bc(st["scale"])
+        if cfg.grad_norm == "absmean":
+            g = (g / (jnp.mean(jnp.abs(g), axis=axes, keepdims=True) + 1e-12)
+                 * cfg.device_p.dw_min)
+        # per-tile kp/kw key chain, identical to update()'s split(key, 3)
+        ks = jax.vmap(lambda kr: jax.random.key_data(
+            jax.random.split(jax.random.wrap_key_data(kr), 3)))(keys_raw)
+        kp, kw = ks[:, 0], ks[:, 1]
+        alpha = lr * cfg.lr_p
+        beta = lr * cfg.lr_w
+        dev_w = dev_of("w")
+        dev_p = dev_of("p") if (st.get("dev_p") is not None
+                                or st.get("seed_p") is not None) else None
 
     if a == "sgd":
-        dw = -beta * g
+        with jax.named_scope("pulse_operands"):
+            dw = -beta * g
         st["W"] = au(st["W"], dw, dev_w, cfg.device_w, kw)
         metrics = base_metrics(dw_w=dw)
 
     elif a in ("ttv1", "ttv2", "agad"):
         c = bc(st["c"]) if a == "agad" else jnp.ones((), jnp.float32)
-        dp = -alpha * c * g
+        with jax.named_scope("pulse_operands"):
+            dp = -alpha * c * g
         st["P"] = au(st["P"], dp, dev_p, cfg.device_p, kp)
         do_transfer = bc((st["t"] % cfg.transfer_every) == 0)
         read = st["P"].astype(jnp.float32)
@@ -365,11 +379,13 @@ def update_batched(
 
     elif a in ("residual", "rider", "erider"):
         c = bc(st["c"]) if a == "erider" else jnp.ones((), jnp.float32)
-        dp = -alpha * c * g
+        with jax.named_scope("pulse_operands"):
+            dp = -alpha * c * g
         st["P"] = au(st["P"], dp, dev_p, cfg.device_p, kp)
         p_new = st["P"].astype(jnp.float32)
         q_ref = st["Qt"] if a == "erider" else st["Qd"]
-        dw = beta * c * (p_new - q_ref.astype(jnp.float32))
+        with jax.named_scope("pulse_operands"):
+            dw = beta * c * (p_new - q_ref.astype(jnp.float32))
         if cfg.buffered_transfer:
             thr = cfg.threshold * cfg.device_w.dw_min
             h = st["H"] + dw
@@ -381,7 +397,8 @@ def update_batched(
                         + cfg.eta * p_new).astype(st["Qd"].dtype)
         metrics = base_metrics(dw_p=dp, dw_w=dw)
         if a == "erider" and cfg.metrics != "none":
-            metrics["prog_events"] = st["prog"].astype(jnp.float32)
+            with jax.named_scope("tile_metrics"):
+                metrics["prog_events"] = st["prog"].astype(jnp.float32)
 
     else:
         raise ValueError(a)

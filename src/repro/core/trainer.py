@@ -62,6 +62,15 @@ def _crc_fold(key, name: str):
     (independent of enumeration order / co-trained tiles)."""
     return jax.random.fold_in(key, np.uint32(zlib.crc32(name.encode())))
 
+# Names of the train step's phases, as ``jax.named_scope`` writes them into
+# the op_name metadata of the compiled program: the five phases of
+# ``train_step`` in order, then the parts of the pulse update inside
+# ``tile_update`` (core/algorithms.py, kernels/ops.py). Metadata only: the
+# scopes add no operation. The benchmark attributes device time to them.
+TRACE_SCOPES = ("tile_begin", "tile_effective", "fwd_bwd", "digital_opt",
+                "tile_update", "pulse_operands", "pulse_kernel",
+                "tile_metrics")
+
 PathPredicate = Callable[[str, Any], bool]
 LossFn = Callable[[Any, Any, jax.Array], Tuple[jax.Array, Dict[str, jax.Array]]]
 
@@ -468,122 +477,130 @@ class AnalogTrainer:
         # group under the group's policy TileConfig, scanned per
         # same-structure same-policy class (grouped engine), or one per
         # tile (legacy looped engine)
-        if grouped:
-            bank: TileBank = state["tiles"]
-            begun = self._grouped_apply(
-                bank,
-                lambda gcfg: _vmap_tile(
-                    lambda ts, k: alg.begin_step(ts, k, gcfg)),
-                k_begin)
-            tiles = TileBank.from_classes(begun, bank.index, bank.class_index,
-                                          bank.policies)
-            path_cfgs = None
-        else:
-            path_cfgs = {p: self._tile_cfg_of(p) for p in state["tiles"]}
-            tiles = {
-                p: alg.begin_step(ts, _crc_fold(k_begin, p), path_cfgs[p])
-                for p, ts in sorted(state["tiles"].items())
-            }
+        with jax.named_scope("tile_begin"):
+            if grouped:
+                bank: TileBank = state["tiles"]
+                begun = self._grouped_apply(
+                    bank,
+                    lambda gcfg: _vmap_tile(
+                        lambda ts, k: alg.begin_step(ts, k, gcfg)),
+                    k_begin)
+                tiles = TileBank.from_classes(begun, bank.index, bank.class_index,
+                                              bank.policies)
+                path_cfgs = None
+            else:
+                path_cfgs = {p: self._tile_cfg_of(p) for p in state["tiles"]}
+                tiles = {
+                    p: alg.begin_step(ts, _crc_fold(k_begin, p), path_cfgs[p])
+                    for p, ts in sorted(state["tiles"].items())
+                }
 
         # phase 2: fwd/bwd on effective weights (with grad accumulation)
-        eff = merge_effective(state["params"], tiles, tcfg, path_cfgs)
-        mb = self.cfg.microbatch
-        if mb <= 1:
-            (loss, aux), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
-                eff, batch, k_model
-            )
-        else:
-            def slice_batch(i):
-                return jax.tree.map(
-                    lambda x: x.reshape(mb, x.shape[0] // mb, *x.shape[1:])[i]
-                    if getattr(x, "ndim", 0) >= 1 else x,
-                    batch,
+        with jax.named_scope("tile_effective"):
+            eff = merge_effective(state["params"], tiles, tcfg, path_cfgs)
+        with jax.named_scope("fwd_bwd"):
+            mb = self.cfg.microbatch
+            if mb <= 1:
+                (loss, aux), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
+                    eff, batch, k_model
                 )
+            else:
+                def slice_batch(i):
+                    return jax.tree.map(
+                        lambda x: x.reshape(mb, x.shape[0] // mb, *x.shape[1:])[i]
+                        if getattr(x, "ndim", 0) >= 1 else x,
+                        batch,
+                    )
 
-            def mb_step(carry, i):
-                g_acc, l_acc, a_acc = carry
-                (l, a), g = jax.value_and_grad(self.loss_fn, has_aux=True)(
-                    eff, slice_batch(i), jax.random.fold_in(k_model, i)
-                )
-                g_acc = jax.tree.map(
-                    lambda acc, gi: acc + gi.astype(self.cfg.accum_dtype), g_acc, g
-                )
-                a_acc = jax.tree.map(lambda x, y: x + y, a_acc, a)
-                return (g_acc, l_acc + l, a_acc), None
+                def mb_step(carry, i):
+                    g_acc, l_acc, a_acc = carry
+                    (l, a), g = jax.value_and_grad(self.loss_fn, has_aux=True)(
+                        eff, slice_batch(i), jax.random.fold_in(k_model, i)
+                    )
+                    g_acc = jax.tree.map(
+                        lambda acc, gi: acc + gi.astype(self.cfg.accum_dtype), g_acc, g
+                    )
+                    a_acc = jax.tree.map(lambda x, y: x + y, a_acc, a)
+                    return (g_acc, l_acc + l, a_acc), None
 
-            # first microbatch outside the scan (defines aux structure)
-            (l0, a0), g0_ = jax.value_and_grad(self.loss_fn, has_aux=True)(
-                eff, slice_batch(0), jax.random.fold_in(k_model, 0)
-            )
-            g0 = jax.tree.map(lambda g: g.astype(self.cfg.accum_dtype), g0_)
-            (grads, loss, aux), _ = jax.lax.scan(
-                mb_step, (g0, l0.astype(jnp.float32), a0), jnp.arange(1, mb)
-            )
-            inv = 1.0 / mb
-            grads = jax.tree.map(lambda g: g * inv, grads)
-            loss = loss * inv
-            aux = jax.tree.map(lambda x: x * inv, aux)
+                # first microbatch outside the scan (defines aux structure)
+                (l0, a0), g0_ = jax.value_and_grad(self.loss_fn, has_aux=True)(
+                    eff, slice_batch(0), jax.random.fold_in(k_model, 0)
+                )
+                g0 = jax.tree.map(lambda g: g.astype(self.cfg.accum_dtype), g0_)
+                (grads, loss, aux), _ = jax.lax.scan(
+                    mb_step, (g0, l0.astype(jnp.float32), a0), jnp.arange(1, mb)
+                )
+                inv = 1.0 / mb
+                grads = jax.tree.map(lambda g: g * inv, grads)
+                loss = loss * inv
+                aux = jax.tree.map(lambda x: x * inv, aux)
 
         lr = lr_at(state["step"], self.cfg.schedule)
 
         # phase 3a: digital branch
-        dgrads = mask_digital_grads(grads, tiles)
-        new_params, new_opt, gnorm = apply_opt(
-            state["params"], dgrads, state["opt"], state["step"], lr, self.cfg.digital
-        )
+        with jax.named_scope("digital_opt"):
+            dgrads = mask_digital_grads(grads, tiles)
+            new_params, new_opt, gnorm = apply_opt(
+                state["params"], dgrads, state["opt"], state["step"], lr,
+                self.cfg.digital)
 
         # phase 3b: analog branch (pulse updates) — grouped engine runs ONE
         # vmapped pulse-update per group over the stacked state (scanned per
         # same-structure class), with a single split-once-per-group key;
         # looped engine is the legacy O(tiles) unrolled reference.
-        agrads = extract_analog_grads(grads, tiles)
-        tile_metrics = []  # per-class (C*n,) metric vectors / per-tile scalars
-        if grouped:
-            # One flat stack + free reshape per class, laid out by the
-            # static class index — grads enter the scan in storage order
-            # with a single rank-(member+1) concatenate (no per-group
-            # restack, no per-step dict re-walk).
-            pidx = dict(tiles.index)
-            stacked_grads = {}
-            for cname, gnames in tiles.class_index:
-                flat = [agrads[p] for g in gnames for p in pidx[g]]
-                cdims = tiles.classes[cname]["W"].shape[:2]
-                arr = (jnp.stack(flat) if len(flat) > 1
-                       else jnp.expand_dims(flat[0], 0))
-                stacked_grads[cname] = arr.reshape(cdims + flat[0].shape)
+        with jax.named_scope("tile_update"):
+            agrads = extract_analog_grads(grads, tiles)
+            tile_metrics = []  # per-class (C*n,) metric vectors / per-tile scalars
+            if grouped:
+                # One flat stack + free reshape per class, laid out by the
+                # static class index — grads enter the scan in storage order
+                # with a single rank-(member+1) concatenate (no per-group
+                # restack, no per-step dict re-walk).
+                pidx = dict(tiles.index)
+                stacked_grads = {}
+                for cname, gnames in tiles.class_index:
+                    flat = [agrads[p] for g in gnames for p in pidx[g]]
+                    cdims = tiles.classes[cname]["W"].shape[:2]
+                    arr = (jnp.stack(flat) if len(flat) > 1
+                           else jnp.expand_dims(flat[0], 0))
+                    stacked_grads[cname] = arr.reshape(cdims + flat[0].shape)
 
-            def make_update_vfn(gcfg):
-                if gcfg.update_backend == "fused":
-                    return lambda ts, kr, grd: alg.update_batched(
-                        ts, grd, kr, gcfg, lr)
-                return _vmap_tile(
-                    lambda ts, k, grd: alg.update(ts, grd, k, gcfg, lr))
+                def make_update_vfn(gcfg):
+                    if gcfg.update_backend == "fused":
+                        return lambda ts, kr, grd: alg.update_batched(
+                            ts, grd, kr, gcfg, lr)
+                    return _vmap_tile(
+                        lambda ts, k, grd: alg.update(ts, grd, k, gcfg, lr))
 
-            res = self._grouped_apply(
-                tiles, make_update_vfn, k_upd, extras=(stacked_grads,))
-            new_tiles = TileBank.from_classes(
-                {c: res[c][0] for c, _ in tiles.class_index},
-                tiles.index, tiles.class_index, tiles.policies)
-            tile_metrics = [
-                jax.tree.map(lambda v: v.reshape(-1), res[c][1])
-                for c, _ in tiles.class_index]
-        else:
-            new_tiles = {}
-            for p, ts in sorted(tiles.items()):
-                ts2, m = alg.update(ts, agrads[p], _crc_fold(k_upd, p),
-                                    path_cfgs[p], lr)
-                new_tiles[p] = ts2
-                tile_metrics.append(m)
+                res = self._grouped_apply(
+                    tiles, make_update_vfn, k_upd, extras=(stacked_grads,))
+                new_tiles = TileBank.from_classes(
+                    {c: res[c][0] for c, _ in tiles.class_index},
+                    tiles.index, tiles.class_index, tiles.policies)
+                tile_metrics = [
+                    jax.tree.map(lambda v: v.reshape(-1), res[c][1])
+                    for c, _ in tiles.class_index]
+            else:
+                new_tiles = {}
+                for p, ts in sorted(tiles.items()):
+                    ts2, m = alg.update(ts, agrads[p], _crc_fold(k_upd, p),
+                                        path_cfgs[p], lr)
+                    new_tiles[p] = ts2
+                    tile_metrics.append(m)
 
-        metrics = {"loss": loss, "lr": lr, "grad_norm": gnorm, **aux}
-        if tile_metrics:
             # mixed plans: metric key sets differ per algorithm — aggregate
             # the union over whichever groups emit each key
-            keys = sorted({k for m in tile_metrics for k in m})
-            for k in keys:
-                vals = jnp.concatenate(
-                    [jnp.atleast_1d(m[k]) for m in tile_metrics if k in m])
-                metrics[f"tile/{k}"] = jnp.sum(vals) if k in ("pulses", "prog_events") else jnp.mean(vals)
+            tile_agg = {}
+            with jax.named_scope("tile_metrics"):
+                for k in sorted({k for m in tile_metrics for k in m}):
+                    vals = jnp.concatenate(
+                        [jnp.atleast_1d(m[k]) for m in tile_metrics if k in m])
+                    tile_agg[f"tile/{k}"] = (
+                        jnp.sum(vals) if k in ("pulses", "prog_events")
+                        else jnp.mean(vals))
+
+        metrics = {"loss": loss, "lr": lr, "grad_norm": gnorm, **aux, **tile_agg}
 
         new_state = TrainState(
             step=state["step"] + 1,

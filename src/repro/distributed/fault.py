@@ -61,10 +61,17 @@ class StragglerMonitor:
         self._last = time.monotonic()
 
     def stop(self) -> bool:
-        """Record one step; returns True if this step was a straggler."""
+        """Record one step timed since ``start()``; returns True if this
+        step was a straggler."""
         assert self._last is not None, "call start() first"
         dt = time.monotonic() - self._last
         self._last = None
+        return self.record(dt)
+
+    def record(self, dt: float) -> bool:
+        """Record one step of ``dt`` seconds, timed by the caller (for a
+        jitted step, up to a sync on its result); returns True if this
+        step was a straggler."""
         self.count += 1
         if self.count <= self.warmup:
             self.ema = dt if self.ema == 0.0 else (self.decay * self.ema + (1 - self.decay) * dt)

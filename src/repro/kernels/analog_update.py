@@ -27,6 +27,8 @@ from .ref import uniform_from_bits
 # comfortably inside a 16 MiB VMEM budget; last dim is a multiple of 128
 # (lane width) and second-to-last a multiple of 8 (sublane width).
 DEFAULT_BLOCK = (256, 512)
+# stable name of the kernel in compiled programs and device traces
+KERNEL_NAME = "analog_pulse_update"
 
 
 def _kernel(
@@ -124,5 +126,6 @@ def analog_update_pallas(
             in_specs=[spec] * 6,
             out_specs=spec,
             interpret=interpret,
+            name=KERNEL_NAME,
         ),
         w, dw, gamma, rho, ubits, zeta)

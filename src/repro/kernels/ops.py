@@ -136,42 +136,51 @@ def analog_update(
         return (jax.random.bits(ku, shape, dtype=jnp.uint32),
                 jax.random.normal(kz, shape, dtype=jnp.float32))
 
+    # named scopes (core/trainer.py TRACE_SCOPES): noise draws and padding
+    # are "pulse_operands", the kernel or its jnp oracle "pulse_kernel"
     if backend() != "pallas":
         # Pure-jnp path operates on the ORIGINAL shapes: everything is
         # element-wise, and any reshape/pad of a (scan, zero, model)-sharded
         # tile array would force GSPMD to rematerialize it replicated.
-        ubits, zeta = make_noise(w.shape)
-        return ref.analog_update_ref(w, dw, gamma, rho, ubits, zeta, **kwargs)
+        with jax.named_scope("pulse_operands"):
+            ubits, zeta = make_noise(w.shape)
+        with jax.named_scope("pulse_kernel"):
+            return ref.analog_update_ref(w, dw, gamma, rho, ubits, zeta,
+                                         **kwargs)
 
     shape = w.shape
-    ubits, zeta = make_noise(shape)
+    with jax.named_scope("pulse_operands"):
+        ubits, zeta = make_noise(shape)
     if w.ndim == 3:
         # Tile-stack fast path: keep the member axis as the outermost kernel
         # grid dimension instead of flattening members into one 2-D view.
         m, n = shape[1:]
         bm, bn = _update_block(m, n)
         pad3 = lambda x, fill=0.0: _pad3d(x, bm, bn, fill=fill)
-        out = analog_update_pallas(
-            pad3(w), pad3(dw), pad3(gamma, fill=1.0), pad3(rho),
-            pad3(ubits, fill=jnp.uint32(1 << 31)), pad3(zeta),
-            block=(bm, bn), **kwargs,
-        )
+        with jax.named_scope("pulse_operands"):
+            args = (pad3(w), pad3(dw), pad3(gamma, fill=1.0), pad3(rho),
+                    pad3(ubits, fill=jnp.uint32(1 << 31)), pad3(zeta))
+        with jax.named_scope("pulse_kernel"):
+            out = analog_update_pallas(*args, block=(bm, bn), **kwargs)
         return out[:, :m, :n]
     w2 = _view2d(w)
     m, n = w2.shape
     bm, bn = _update_block(m, n)
-    w2 = _pad2d(w2, bm, bn)
-    dw2 = _pad2d(_view2d(dw), bm, bn)
-    g2 = _pad2d(_view2d(gamma), bm, bn, fill=1.0)
-    r2 = _pad2d(_view2d(rho), bm, bn)
-    # Draw noise at the ORIGINAL shape so ref and pallas consume identical
-    # random bits for any (possibly non-block-multiple) tile, then pad into
-    # the kernel grid: ubits=2^31 / zeta=0 keep the dw=0 padding inert.
-    u2 = _pad2d(_view2d(ubits), bm, bn, fill=jnp.uint32(1 << 31))
-    z2 = _pad2d(_view2d(zeta), bm, bn)
-    out = analog_update_pallas(
-        w2, dw2, g2, r2, u2, z2, block=(bm, bn), **kwargs
-    )
+    with jax.named_scope("pulse_operands"):
+        w2 = _pad2d(w2, bm, bn)
+        dw2 = _pad2d(_view2d(dw), bm, bn)
+        g2 = _pad2d(_view2d(gamma), bm, bn, fill=1.0)
+        r2 = _pad2d(_view2d(rho), bm, bn)
+        # Draw noise at the ORIGINAL shape so ref and pallas consume
+        # identical random bits for any (possibly non-block-multiple) tile,
+        # then pad into the kernel grid: ubits=2^31 / zeta=0 keep the dw=0
+        # padding inert.
+        u2 = _pad2d(_view2d(ubits), bm, bn, fill=jnp.uint32(1 << 31))
+        z2 = _pad2d(_view2d(zeta), bm, bn)
+    with jax.named_scope("pulse_kernel"):
+        out = analog_update_pallas(
+            w2, dw2, g2, r2, u2, z2, block=(bm, bn), **kwargs
+        )
     return out[:m, :n].reshape(shape)
 
 

@@ -189,15 +189,17 @@ def main(argv=None) -> dict:
     for step in range(start_step, args.steps):
         if step > start_step:
             batch = jax.device_put(next(it), batch_sh)
-        monitor.start()
-        state, metrics = step_fn(state, batch)
-        straggler = monitor.stop()
+        # a profiler step: on the device trace's clock, xprof's step view
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            state, metrics = step_fn(state, batch)
         if step % args.log_every == 0 or step == args.steps - 1:
-            m = {k: float(v) for k, v in metrics.items()}
+            m = {k: float(v) for k, v in metrics.items()}  # waits for the step
             now = time.perf_counter()
             m["step"] = step
             m["step_s"] = (now - t_log) / (step + 1 - s_log)
-            m["straggler"] = bool(straggler)
+            # the dispatch returns before the device finishes: the monitor
+            # is fed the synced time per step since the previous log
+            m["straggler"] = monitor.record(m["step_s"])
             t_log, s_log = now, step + 1
             history.append(m)
             print(f"[train] step={step} loss={m['loss']:.4f} "

@@ -115,6 +115,31 @@ def test_straggler_monitor_flags_slow_step():
     assert m.flagged == 1
 
 
+def test_train_loop_feeds_the_straggler_monitor_synced_step_times(monkeypatch):
+    """``launch.train`` dispatches each step asynchronously, so the time of
+    the call alone is the dispatch's. The monitor is fed the per-step time
+    of each log, taken after the loop waited for that step's metrics, and
+    never brackets a dispatch."""
+    from repro.distributed import fault
+    from repro.launch import train
+
+    fed = []
+
+    class Recording(fault.StragglerMonitor):
+        def start(self):
+            raise AssertionError("the loop must not time the dispatch")
+
+        def record(self, dt):
+            fed.append(dt)
+            return super().record(dt)
+
+    monkeypatch.setattr(train, "StragglerMonitor", Recording)
+    r = train.main(["--arch", "qwen2-0.5b", "--smoke", "--steps", "4",
+                    "--batch", "2", "--seq", "16", "--log-every", "2"])
+    assert fed == [m["step_s"] for m in r["history"]]
+    assert len(fed) == 3 and all(dt > 0 for dt in fed)
+
+
 def test_preemption_and_restart():
     from repro.distributed.fault import PreemptionHandler, RestartPolicy
 

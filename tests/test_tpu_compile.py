@@ -10,6 +10,7 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,3 +100,29 @@ def test_vmapped_ops_update_compiles_for_v5e(one_chip, pallas_backend):
 
     text = _compiled_text(update, (w, dw, gamma, rho, keys))
     assert "tpu_custom_call" in text
+
+
+def test_kernel_is_named_in_the_compiled_program(one_chip, pallas_backend):
+    """``ops.analog_update`` on a v5e: the Mosaic kernel is the instruction
+    named ``analog_pulse_update``, its op_name under the ``pulse_kernel``
+    scope, and the noise it reads under ``pulse_operands``."""
+    from repro.kernels.analog_update import KERNEL_NAME
+
+    shape = (2, 64, 256)
+    w, dw, gamma, rho = (jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+                         for dt in (jnp.bfloat16, jnp.float32, jnp.float32,
+                                    jnp.float32))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+
+    def update(w, dw, gamma, rho, kr):
+        return ops.analog_update(w, dw, gamma, rho,
+                                 jax.random.wrap_key_data(kr), rng="hash", **KW)
+
+    text = _compiled_text(update, (w, dw, gamma, rho, key))
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 1
+    assert re.match(rf"\s*(ROOT )?%{KERNEL_NAME}\.\d+ = ", kernels[0])
+    assert re.search(rf'op_name="[^"]*/pulse_kernel/[^"]*/{KERNEL_NAME}/',
+                     kernels[0])
+    assert "/pulse_operands/" in text
