@@ -11,6 +11,11 @@ d_ff 4864, vocab 151936; random weights from fixed seeds):
    reference on one tile stack of the MLP, with the same random operands:
    they must agree within one bf16 ulp, and the compiled program must hold
    the kernel (``tpu_custom_call``);
+   then the seeded kernel, which draws the device parameters and the noise
+   itself, against the operand path that draws them in XLA from the same
+   seeds (the benchmark's P device): they must agree within one bf16 ulp;
+   and whether the chip's XLA uint32 -> f32 conversion equals the
+   two-halves one the kernel uses (``fastrng.u32_to_f32``);
 3. ``repro.launch.train.main`` for 5 E-RIDER steps at batch 8 x 128
    tokens: compile seconds, every loss (all finite), seconds per step, peak
    device memory, and the kernel in the compiled train step;
@@ -44,7 +49,9 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.kernels import ops  # noqa: E402
+from repro.core.device import DeviceConfig, sample_device  # noqa: E402
+from repro.kernels import fastrng, ops  # noqa: E402
+from repro.kernels.analog_update import SEEDED_KERNEL_NAME  # noqa: E402
 from repro.launch import compile_cache, serve, train  # noqa: E402
 
 ARCH = "qwen2-0.5b"
@@ -57,6 +64,11 @@ KERNEL_SHAPE = (24, 896, 4864)
 # never the trajectory.
 LOSS_RTOL = 1e-2
 BF16_MANTISSA_BITS = 7
+# the P device of the benchmark's qwen2-0.5b tile configuration: every
+# device draw (slope, asymmetry, SP target) is made
+P_DEVICE = DeviceConfig(kind="softbounds", dw_min=1e-4, sigma_d2d=0.1,
+                        sigma_pm=0.3, sigma_c2c=0.05, ref_mean=0.1,
+                        ref_std=0.1)
 
 
 def log(msg: str) -> None:
@@ -112,6 +124,60 @@ def kernel_parity(shape=KERNEL_SHAPE) -> dict:
             "moved": int(np.count_nonzero(outs["ref"] != np.asarray(
                 w.astype(jnp.float32)))),
             "kernel_in_hlo": "tpu_custom_call" in text}
+
+
+def seeded_parity(shape=KERNEL_SHAPE, device=P_DEVICE) -> dict:
+    """One tile stack through the seeded kernel and through the operand
+    path (``sample_device(method='hash')`` and fastrng noise in XLA, then
+    the operand kernel), same device and noise seeds."""
+    ks = jax.random.split(jax.random.PRNGKey(12), 4)
+    w = jax.random.uniform(ks[0], shape, jnp.float32, -0.5, 0.5
+                           ).astype(jnp.bfloat16)
+    dw = 3e-4 * jax.random.normal(ks[1], shape)
+    dev_seed = jax.random.key_data(ks[2]).astype(jnp.uint32)
+    kw = dict(dw_min=device.dw_min, tau_min=device.tau_min,
+              tau_max=device.tau_max, sigma_c2c=device.sigma_c2c)
+
+    def seeded(w, dw, dev_seed, key):
+        return ops.analog_update_seeded(w, dw, dev_seed, key, device=device)
+
+    def operands(w, dw, dev_seed, key):
+        dp = sample_device(jax.random.wrap_key_data(dev_seed), w.shape,
+                           device, method="hash")
+        return ops.analog_update(w, dw, dp["gamma"], dp["rho"], key,
+                                 rng="hash", **kw)
+
+    args = (w, dw, dev_seed, ks[3])
+    outs, texts = {}, {}
+    ops.set_backend("pallas")
+    try:
+        for name, fn in (("seeded", seeded), ("operands", operands)):
+            c = jax.jit(fn).lower(*args).compile()
+            texts[name] = c.as_text()
+            outs[name] = np.asarray(c(*args).astype(jnp.float32))
+    finally:
+        ops.set_backend(None)
+    ulps = bf16_ulps(outs["seeded"], outs["operands"])
+    return {"shape": list(shape), "max_ulps": float(ulps.max()),
+            "differing": int(np.count_nonzero(ulps)),
+            "moved": int(np.count_nonzero(outs["operands"] != np.asarray(
+                w.astype(jnp.float32)))),
+            "kernel_in_hlo": ("tpu_custom_call" in texts["seeded"]
+                              and SEEDED_KERNEL_NAME in texts["seeded"])}
+
+
+def u32_conversion(n: int = 1 << 22) -> dict:
+    """XLA's uint32 -> f32 conversion against ``fastrng.u32_to_f32`` on the
+    lattice edges and ``n`` hashed values."""
+    edges = jnp.array([0, 1, (1 << 24) + 1, (1 << 31) + 129, 0xFFFFFF7F,
+                       0xFFFFFF80, 0xFFFFFFFF], jnp.uint32)
+    bits = jnp.concatenate(
+        [edges, fastrng.hash_bits(jnp.array([5, 7], jnp.uint32), (n,), 3)])
+    xla, halves = jax.jit(lambda b: (b.astype(jnp.float32),
+                                     fastrng.u32_to_f32(b)))(bits)
+    return {"values": int(bits.size),
+            "differing": int(np.count_nonzero(np.asarray(xla)
+                                              != np.asarray(halves)))}
 
 
 def peak_bytes():
@@ -235,6 +301,18 @@ def main(argv=None) -> None:
     check(k["max_ulps"] <= 1.0, "Pallas and reference differ by > 1 ulp")
     check(k["moved"] > 0, "the update moved no weight")
     check(k["kernel_in_hlo"], "no tpu_custom_call in the kernel program")
+    t0 = time.perf_counter()
+    sp = seeded_parity()
+    log(f"seeded kernel parity {sp['shape']} bf16: max {sp['max_ulps']!r} "
+        f"bf16 ulp, {sp['differing']} elements differ, {sp['moved']} moved, "
+        f"{SEEDED_KERNEL_NAME} kernel={sp['kernel_in_hlo']} "
+        f"({time.perf_counter() - t0:.1f}s)")
+    u = u32_conversion()
+    log(f"uint32 -> f32: XLA and the two-halves conversion differ on "
+        f"{u['differing']} of {u['values']} values")
+    check(sp["max_ulps"] <= 1.0, "seeded kernel and operand path differ by > 1 ulp")
+    check(sp["moved"] > 0, "the seeded update moved no weight")
+    check(sp["kernel_in_hlo"], f"no {SEEDED_KERNEL_NAME} kernel")
 
     work = tempfile.mkdtemp(prefix="chip_smoke_")
     try:

@@ -44,25 +44,27 @@ Metrics = Dict[str, jnp.ndarray]
 # ---------------------------------------------------------------------------
 
 
-def _au(x, dx, dev, dcfg, key, cfg: TileConfig):
-    return analog_update(x, dx, dev, dcfg, key, bl=cfg.bl, mode=cfg.pulse_mode,
-                         rng=cfg.rng)
+def _au(x, dx, dev, dcfg, key, cfg: TileConfig, with_device: bool = False):
+    """The pulse update of ``x``. ``with_device`` returns ``(x_new,
+    params)``: the device params the update used where the diagnostics read
+    them (``metrics='full'``), else None. A seeded device is then drawn
+    once per step, not again for ``gp_sq``/``sp_err``."""
+    keep = with_device and cfg.metrics == "full"
+    out = analog_update(x, dx, dev, dcfg, key, bl=cfg.bl, mode=cfg.pulse_mode,
+                        rng=cfg.rng, with_device=keep)
+    return (out, None) if with_device and not keep else out
 
 
-def _dev(st: TileState, which: str, cfg: TileConfig, shape):
-    """Fetch device params; regenerate from the tile seed when not stored
-    (store_device=False — DESIGN.md §3 memory/compute trade)."""
+def _dev(st: TileState, which: str):
+    """The device of array ``which``: its stored params, else the tile
+    seed, which the pulse update draws them from (store_device=False —
+    DESIGN.md §3 memory/compute trade; ``pulse.analog_update``)."""
     dev = st.get(f"dev_{which}")
-    if dev is not None:
-        return dev
-    from .device import sample_device
-
-    key = jax.random.wrap_key_data(st[f"seed_{which}"])
-    dcfg = cfg.device_p if which == "p" else cfg.device_w
-    return sample_device(key, shape, dcfg, method=cfg.rng)
+    return dev if dev is not None else st[f"seed_{which}"]
 
 
-def _base_metrics(cfg: TileConfig, st: TileState, dw_p=None, dw_w=None) -> Metrics:
+def _base_metrics(cfg: TileConfig, st: TileState, dw_p=None, dw_w=None,
+                  dev_p=None) -> Metrics:
     # each diagnostic below is an extra full pass + reduction over the tile;
     # cfg.metrics trades them away at LM scale ('pulses' / 'none')
     with jax.named_scope("tile_metrics"):
@@ -75,11 +77,9 @@ def _base_metrics(cfg: TileConfig, st: TileState, dw_p=None, dw_w=None) -> Metri
         if dw_w is not None:
             pulses = pulses + expected_pulses(dw_w, cfg.device_w.dw_min, cfg.bl)
         m["pulses"] = pulses
-        has_dev_p = st.get("dev_p") is not None or st.get("seed_p") is not None
         if cfg.metrics == "pulses":
             return m
-        if st.get("P") is not None and has_dev_p:
-            dev_p = _dev(st, "p", cfg, st["P"].shape)
+        if dev_p is not None:
             _, g = fg(st["P"].astype(jnp.float32), dev_p, cfg.device_p)
             m["gp_sq"] = jnp.mean(g * g)
             if st.get("Qd") is not None:
@@ -164,15 +164,15 @@ def update(
 ) -> Tuple[TileState, Metrics]:
     a = cfg.algorithm
     st = TileState(st)
-    # the kernel's inputs: analog-space gradient, keys and device
-    # parameters (regenerated from the tile seed when not stored)
+    # the kernel's inputs: analog-space gradient, keys and devices (params,
+    # or the tile seed the update draws them from)
     with jax.named_scope("pulse_operands"):
         g = _grad_to_analog(st, grad, cfg)
         kp, kw, kq = jax.random.split(key, 3)
         alpha = lr * cfg.lr_p
         beta = lr * cfg.lr_w
-        dev_w = _dev(st, "w", cfg, st["W"].shape)
-        dev_p = _dev(st, "p", cfg, st["W"].shape) if (
+        dev_w = _dev(st, "w")
+        dev_p = _dev(st, "p") if (
             st.get("dev_p") is not None or st.get("seed_p") is not None) else None
 
     if a == "sgd":
@@ -185,7 +185,8 @@ def update(
         c = st["c"] if a == "agad" else jnp.ones((), jnp.float32)
         with jax.named_scope("pulse_operands"):
             dp = -alpha * c * g
-        st["P"] = _au(st["P"], dp, dev_p, cfg.device_p, kp, cfg)
+        st["P"], dev_p = _au(st["P"], dp, dev_p, cfg.device_p, kp, cfg,
+                              with_device=True)
         do_transfer = (st["t"] % cfg.transfer_every) == 0
         read = st["P"].astype(jnp.float32)  # analog readout of the fast array
         if a == "ttv1":
@@ -208,14 +209,15 @@ def update(
             dw = n * thr
             st["H"] = h - dw
             st["W"] = _au(st["W"], dw, dev_w, cfg.device_w, kw, cfg)
-        metrics = _base_metrics(cfg, st, dw_p=dp, dw_w=dw)
+        metrics = _base_metrics(cfg, st, dw_p=dp, dw_w=dw, dev_p=dev_p)
 
     elif a in ("residual", "rider", "erider"):
         c = st["c"] if a == "erider" else jnp.ones((), jnp.float32)
         # (11a)/(18a): P <- P - alpha c grad  (asymmetric pulse update)
         with jax.named_scope("pulse_operands"):
             dp = -alpha * c * g
-        st["P"] = _au(st["P"], dp, dev_p, cfg.device_p, kp, cfg)
+        st["P"], dev_p = _au(st["P"], dp, dev_p, cfg.device_p, kp, cfg,
+                              with_device=True)
         p_new = st["P"].astype(jnp.float32)
         # (11b)/(18b): W <- W + beta c (P_{k+1} - Q_k)
         q_ref = st["Qt"] if a == "erider" else st["Qd"]
@@ -232,7 +234,7 @@ def update(
         if a in ("rider", "erider"):
             st["Qd"] = ((1.0 - cfg.eta) * st["Qd"].astype(jnp.float32)
                         + cfg.eta * p_new).astype(st["Qd"].dtype)
-        metrics = _base_metrics(cfg, st, dw_p=dp, dw_w=dw)
+        metrics = _base_metrics(cfg, st, dw_p=dp, dw_w=dw, dev_p=dev_p)
         if a == "erider" and cfg.metrics != "none":
             with jax.named_scope("tile_metrics"):
                 metrics["prog_events"] = st["prog"].astype(jnp.float32)

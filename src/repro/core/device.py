@@ -23,6 +23,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import fastrng
 from repro.kernels import ref as kref
 
 
@@ -144,35 +145,13 @@ def sample_device(key, shape, cfg: DeviceConfig, method: str = "threefry") -> De
     regeneration path at LM scale; see kernels/fastrng.py).
     """
     if method == "hash":
-        from repro.kernels import fastrng
-
         seed = fastrng.seed_from_key(key)
-        n_g = fastrng.hash_normal(seed, shape, 11)
-        n_r = fastrng.hash_normal(seed, shape, 13)
-        n_s = fastrng.hash_normal(seed, shape, 17)
+        gamma, rho = kref.hash_device(seed[0], seed[1],
+                                      fastrng.linear_index(shape), cfg)
     else:
-        kg, kr, ks = jax.random.split(key, 3)
-        n_g = jax.random.normal(kg, shape, jnp.float32)
-        n_r = jax.random.normal(kr, shape, jnp.float32)
-        n_s = jax.random.normal(ks, shape, jnp.float32)
-    if cfg.sigma_d2d > 0:
-        gamma = jnp.exp(cfg.sigma_d2d * n_g)
-    else:
-        gamma = jnp.ones(shape, jnp.float32)
-    # Def. 2.1 positive-definiteness: |rho| < gamma keeps both alpha+- > 0
-    rho = jnp.clip(cfg.sigma_pm * n_r, -0.95 * gamma, 0.95 * gamma)
-
-    if cfg.ref_mean != 0.0 or cfg.ref_std != 0.0:
-        # Solve for rho that realizes a target SP w* ~ N(ref_mean, ref_std^2):
-        #   w* = 2 rho / ((gamma+rho)/tmax + (gamma-rho)/tmin)
-        # => rho = w* gamma (tmin + tmax) / (2 tmin tmax + w*(tmin - tmax))
-        w_star = cfg.ref_mean + cfg.ref_std * n_s
-        w_star = jnp.clip(w_star, -0.95 * cfg.tau_min, 0.95 * cfg.tau_max)
-        num = w_star * gamma * (cfg.tau_min + cfg.tau_max)
-        den = 2.0 * cfg.tau_min * cfg.tau_max + w_star * (cfg.tau_min - cfg.tau_max)
-        rho = num / den
-        # keep alpha+- positive (Def. 2.1 positive-definiteness)
-        rho = jnp.clip(rho, -0.95 * gamma, 0.95 * gamma)
+        keys = jax.random.split(key, 3)
+        gamma, rho = kref.device_params(
+            lambda i: jax.random.normal(keys[i], shape, jnp.float32), shape, cfg)
     return DeviceParams(gamma=gamma, rho=rho)
 
 

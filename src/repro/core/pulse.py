@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
-from .device import DeviceConfig, DeviceParams, fg, responses
+from .device import DeviceConfig, DeviceParams, fg, responses, sample_device
 
 
 def analog_update(
@@ -32,24 +32,45 @@ def analog_update(
     mode: str = "fused",
     rng: str = "threefry",
     noise=None,
+    with_device: bool = False,
 ):
     """Apply desired increment ``dw`` to analog array ``w`` via pulses.
 
+    ``dp`` is the device's ``DeviceParams``, or the (2,) uint32 seed that
+    ``sample_device(method=rng)`` draws them from: the fused soft-bounds
+    update under the hash RNG then draws them, with its noise, element by
+    element where it uses them (``kops.analog_update_seeded``); every other
+    case samples them here. ``with_device`` returns ``(w_new,
+    DeviceParams)``, the params the update used, for a caller that reads
+    them too.
     ``noise`` optionally carries pre-drawn ``(ubits, zeta)`` (uint32 bits
     for the stochastic rounding + standard normal for c2c); the grouped
     engine's fused backend passes one batched stream for a whole stack.
     """
-    if cfg.kind in ("softbounds", "linear") and mode == "fused":
-        return kops.analog_update(
+    fused = cfg.kind in ("softbounds", "linear") and mode == "fused"
+    if not isinstance(dp, dict):  # a seed
+        if fused and rng == "hash":
+            out = kops.analog_update_seeded(w, dw, dp, key, device=cfg, bl=bl,
+                                            with_device=with_device)
+            if not with_device:
+                return out
+            return out[0], DeviceParams(gamma=out[1], rho=out[2])
+        with jax.named_scope("pulse_operands"):
+            dp = sample_device(jax.random.wrap_key_data(dp), w.shape, cfg,
+                               method=rng)
+    if fused:
+        out = kops.analog_update(
             w, dw, dp["gamma"], dp["rho"], key,
             dw_min=cfg.dw_min, tau_min=cfg.tau_min, tau_max=cfg.tau_max,
             sigma_c2c=cfg.sigma_c2c, bl=bl, rng=rng, noise=noise,
         )
-    if mode == "fused":
-        return _fused_generic(w, dw, dp, cfg, key, bl=bl, noise=noise)
-    if mode == "train":
-        return _pulse_train(w, dw, dp, cfg, key, bl=max(bl, 1))
-    raise ValueError(f"unknown pulse mode {mode}")
+    elif mode == "fused":
+        out = _fused_generic(w, dw, dp, cfg, key, bl=bl, noise=noise)
+    elif mode == "train":
+        out = _pulse_train(w, dw, dp, cfg, key, bl=max(bl, 1))
+    else:
+        raise ValueError(f"unknown pulse mode {mode}")
+    return (out, dp) if with_device else out
 
 
 def _stochastic_round(x, key):

@@ -5,7 +5,10 @@ whole arrays; under GSPMD the loop blocks backward sharding propagation, so
 the bit arrays materialize *replicated* — hundreds of MB of HBM per tile per
 step. This module derives randomness from a murmur3-style integer hash of
 (linear index, seed, salt): a short elementwise chain that XLA fuses into
-the consumer (zero extra HBM traffic) and GSPMD shards with it.
+the consumer and GSPMD shards with it. Its per-element functions
+(``hash_at``, ``u32_to_f32``, ``normal_from_bits``) also run inside the
+seeded pulse-update kernel (``kernels/analog_update.py``), which draws the
+same bits in VMEM.
 
 Statistical quality is far above the needs of stochastic pulse rounding and
 c2c noise (verified empirically in tests/test_properties.py); the
@@ -29,26 +32,55 @@ def _finalize(x):
     return x
 
 
-def hash_bits(seed, shape, salt: int):
-    """seed: (2,) uint32; returns uint32 array of ``shape``.
+def linear_index(shape):
+    """uint32 row-major position of every element of ``shape``.
 
-    The linear index is built from per-dimension broadcasted_iotas (not a 1-D
-    iota + reshape) so GSPMD can shard the whole chain with its consumer.
+    Built from per-dimension broadcasted_iotas (not a 1-D iota + reshape)
+    so GSPMD can shard the whole chain with its consumer.
     """
     idx = jnp.zeros(shape, jnp.uint32)
     stride = 1
     for d in range(len(shape) - 1, -1, -1):
         idx = idx + jax.lax.broadcasted_iota(jnp.uint32, shape, d) * jnp.uint32(stride)
         stride *= int(shape[d])
-    x = idx * jnp.uint32(0xCC9E2D51) + seed[0] + jnp.uint32((salt * 0x9E3779B9) & 0xFFFFFFFF)
+    return idx
+
+
+def hash_at(seed0, seed1, idx, salt: int):
+    """The hash of (linear index, seed, salt), element by element.
+
+    ``idx`` is a uint32 array of linear indices, ``seed0``/``seed1`` uint32
+    scalars. Every draw of this module goes through it: the XLA paths at
+    ``linear_index(shape)``, the seeded pulse-update kernel at the indices
+    of its block.
+    """
+    x = idx * jnp.uint32(0xCC9E2D51) + seed0 + jnp.uint32((salt * 0x9E3779B9) & 0xFFFFFFFF)
     x = _finalize(x)
-    x = x ^ (seed[1] + jnp.uint32(salt & 0xFFFFFFFF))
+    x = x ^ (seed1 + jnp.uint32(salt & 0xFFFFFFFF))
     return _finalize(x)
+
+
+def hash_bits(seed, shape, salt: int):
+    """seed: (2,) uint32; returns uint32 array of ``shape``."""
+    return hash_at(seed[0], seed[1], linear_index(shape), salt)
+
+
+def u32_to_f32(x):
+    """uint32 -> f32, rounded to nearest even like ``astype(float32)``.
+
+    The two 16-bit halves convert exactly through int32 and their sum
+    rounds once, so the result is the correctly rounded one. The TPU
+    kernel compiler has no uint32 -> f32 cast; this form runs there and
+    in XLA alike, so both draw the same bits.
+    """
+    hi = jax.lax.shift_right_logical(x, jnp.uint32(16)).astype(jnp.int32)
+    lo = (x & jnp.uint32(0xFFFF)).astype(jnp.int32)
+    return hi.astype(jnp.float32) * 65536.0 + lo.astype(jnp.float32)
 
 
 def hash_uniform(seed, shape, salt: int):
     """[0, 1) f32."""
-    return hash_bits(seed, shape, salt).astype(jnp.float32) * (1.0 / 4294967296.0)
+    return u32_to_f32(hash_bits(seed, shape, salt)) * (1.0 / 4294967296.0)
 
 
 # f32 just below 1: clamping |2u - 1| here caps samples at ~5.4 sigma and,
@@ -88,7 +120,12 @@ def _fast_neg_log(y):
 
 
 def hash_normal(seed, shape, salt: int):
-    """Standard normal via the inverse CDF over one hashed uniform.
+    """Standard normal via the inverse CDF over one hashed uniform."""
+    return normal_from_bits(hash_bits(seed, shape, salt))
+
+
+def normal_from_bits(bits):
+    """Standard normal from uint32 hash bits, element by element.
 
     One hash draw + a fused-friendly erfinv (fast bitcast log + Giles'
     polynomials) is ~5x cheaper than Box-Muller's log/cos pair and stays
@@ -96,8 +133,7 @@ def hash_normal(seed, shape, salt: int):
     tests that pass for threefry pass here too. The +0.5 centers the
     uint32 lattice inside (0, 1).
     """
-    u = (hash_bits(seed, shape, salt).astype(jnp.float32) + 0.5) * (
-        1.0 / 4294967296.0)
+    u = (u32_to_f32(bits) + 0.5) * (1.0 / 4294967296.0)
     x = jnp.clip(2.0 * u - 1.0, -_ONE_MINUS_EPS, _ONE_MINUS_EPS)
     w = _fast_neg_log(1.0 - x * x)
     wc = w - 2.5

@@ -19,11 +19,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from . import ref
+from . import fastrng, ref
 from .analog_matmul import DEFAULT_BLOCKS as MVM_BLOCKS
 from .analog_matmul import analog_mvm_pallas
 from .analog_update import DEFAULT_BLOCK as UPD_BLOCK
-from .analog_update import analog_update_pallas
+from .analog_update import SEEDED_BLOCK, analog_update_pallas, analog_update_seeded_pallas
 from .sp_filter import sp_filter_pallas
 
 _BACKEND: Optional[str] = None  # None = auto
@@ -54,10 +54,10 @@ def _fit(dim: int, target: int, align: int) -> int:
     return target
 
 
-def _update_block(m: int, n: int):
+def _update_block(m: int, n: int, target=UPD_BLOCK):
     # sublane blocks in multiples of 16 (bf16 packs two rows per sublane),
     # lane blocks in multiples of 128
-    return _fit(m, UPD_BLOCK[0], 16), _fit(n, UPD_BLOCK[1], 128)
+    return _fit(m, target[0], 16), _fit(n, target[1], 128)
 
 
 def _pad2d(x, bm, bn, fill=0.0):
@@ -127,8 +127,6 @@ def analog_update(
         if noise is not None:
             return noise
         if rng == "hash":
-            from . import fastrng
-
             seed = fastrng.seed_from_key(key)
             return (fastrng.hash_bits(seed, shape, 1),
                     fastrng.hash_normal(seed, shape, 2))
@@ -182,6 +180,40 @@ def analog_update(
             w2, dw2, g2, r2, u2, z2, block=(bm, bn), **kwargs
         )
     return out[:m, :n].reshape(shape)
+
+
+def analog_update_seeded(w, dw, dev_seed, key, *, device, bl: int = 0,
+                         with_device: bool = False):
+    """``analog_update(rng='hash')`` for a device given by its seed.
+
+    ``dev_seed`` is the (2,) uint32 seed that
+    ``core/device.sample_device(method='hash')`` draws ``device``'s
+    ``(gamma, rho)`` from. On the Pallas backend the seeded kernel draws
+    them, and the noise, in VMEM: no weight-sized operand is written. The
+    jnp path draws them over the whole array with the same functions, so
+    both give the same bits. ``with_device`` returns ``(w_new, gamma,
+    rho)``: the device params as drawn, for a reader that needs them too.
+    """
+    kwargs = dict(dw_min=device.dw_min, tau_min=device.tau_min,
+                  tau_max=device.tau_max, sigma_c2c=device.sigma_c2c, bl=bl)
+    if backend() != "pallas":
+        with jax.named_scope("pulse_operands"):
+            gamma, rho = ref.hash_device(dev_seed[0], dev_seed[1],
+                                         fastrng.linear_index(w.shape), device)
+        out = analog_update(w, dw, gamma, rho, key, rng="hash", **kwargs)
+        return (out, gamma, rho) if with_device else out
+    with jax.named_scope("pulse_operands"):
+        seeds = jnp.concatenate([dev_seed, fastrng.seed_from_key(key)])[None]
+    # a row-major 2-D view keeps every element's linear index
+    w2 = _view2d(w)
+    with jax.named_scope("pulse_kernel"):
+        out = analog_update_seeded_pallas(
+            w2, _view2d(dw), seeds, device=device, bl=bl,
+            block=_update_block(*w2.shape, SEEDED_BLOCK),
+            with_device=with_device)
+    if with_device:
+        return tuple(x.reshape(w.shape) for x in out)
+    return out.reshape(w.shape)
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from . import fastrng
+
 # ---------------------------------------------------------------------------
 # Response functions (element-wise; all args broadcastable arrays)
 # ---------------------------------------------------------------------------
@@ -42,6 +44,52 @@ def response_fg(w, gamma, rho, tau_min, tau_max):
     qp = q_plus(w, gamma, rho, tau_max)
     qm = q_minus(w, gamma, rho, tau_min)
     return (qm + qp) * 0.5, (qm - qp) * 0.5
+
+
+# ---------------------------------------------------------------------------
+# Device-to-device sampling (paper App. F.1; core/device.py sample_device)
+# ---------------------------------------------------------------------------
+
+# fastrng salts of a device's three normal fields: slope, asymmetry, SP target
+DEVICE_SALTS = (11, 13, 17)
+
+
+def device_params(normal, shape, dev):
+    """Per-element ``(gamma, rho)`` of a device from standard normals.
+
+    ``normal(i)`` draws normal field ``i`` (0 slope, 1 asymmetry, 2 SP
+    target) at ``shape``; only the fields ``dev`` uses are drawn. ``dev``
+    is a ``core.device.DeviceConfig`` (read for ``sigma_d2d``,
+    ``sigma_pm``, ``ref_mean``, ``ref_std``, ``tau_min``, ``tau_max``).
+    """
+    if dev.sigma_d2d > 0:
+        gamma = jnp.exp(dev.sigma_d2d * normal(0))
+    else:
+        gamma = jnp.ones(shape, jnp.float32)
+    # Def. 2.1 positive-definiteness: |rho| < gamma keeps both alpha+- > 0
+    rho = jnp.clip(dev.sigma_pm * normal(1), -0.95 * gamma, 0.95 * gamma)
+
+    if dev.ref_mean != 0.0 or dev.ref_std != 0.0:
+        # Solve for rho that realizes a target SP w* ~ N(ref_mean, ref_std^2):
+        #   w* = 2 rho / ((gamma+rho)/tmax + (gamma-rho)/tmin)
+        # => rho = w* gamma (tmin + tmax) / (2 tmin tmax + w*(tmin - tmax))
+        w_star = dev.ref_mean + dev.ref_std * normal(2)
+        w_star = jnp.clip(w_star, -0.95 * dev.tau_min, 0.95 * dev.tau_max)
+        num = w_star * gamma * (dev.tau_min + dev.tau_max)
+        den = 2.0 * dev.tau_min * dev.tau_max + w_star * (dev.tau_min - dev.tau_max)
+        rho = num / den
+        # keep alpha+- positive (Def. 2.1 positive-definiteness)
+        rho = jnp.clip(rho, -0.95 * gamma, 0.95 * gamma)
+    return gamma, rho
+
+
+def hash_device(seed0, seed1, idx, dev):
+    """``device_params`` from the hash at linear indices ``idx``: what
+    ``sample_device(method='hash')`` gives at those elements."""
+    return device_params(
+        lambda i: fastrng.normal_from_bits(
+            fastrng.hash_at(seed0, seed1, idx, DEVICE_SALTS[i])),
+        idx.shape, dev)
 
 
 # ---------------------------------------------------------------------------

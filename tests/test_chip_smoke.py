@@ -33,6 +33,17 @@ def test_kernel_parity_phase_interpreted():
     assert not r["kernel_in_hlo"], r
 
 
+def test_seeded_parity_phase_interpreted():
+    """The seeded-kernel phase at a small stack: the interpreted kernel,
+    drawing devices and noise itself, agrees with the operand path bit for
+    bit; and the two-halves uint32 -> f32 conversion equals XLA's here."""
+    r = chip_smoke.seeded_parity((2, 40, 200))
+    assert r["max_ulps"] == 0.0 and r["differing"] == 0, r
+    assert r["moved"] > 0, r
+    assert not r["kernel_in_hlo"], r
+    assert chip_smoke.u32_conversion(1 << 16)["differing"] == 0
+
+
 def test_bf16_ulps():
     a = np.array([1.0, 1.0, -2.0, 0.0], np.float32)
     b = np.array([1.0, 1.0 + 2 ** -7, -2.0 - 2 ** -6, 0.0], np.float32)

@@ -228,3 +228,106 @@ def test_ops_wrappers_arbitrary_rank():
     y = ops.analog_mvm(x, wmat, KEY, inp_res=1 / 126, inp_bound=1.0,
                        out_res=1 / 510, out_bound=12.0, out_noise=0.0)
     assert y.shape == (2, 5, 32)
+
+
+# the benchmark's tile devices: P with a shifted symmetric point (every
+# device draw is made), W with none (two draws)
+SEEDED_P = dict(dw_min=1e-3, sigma_d2d=0.1, sigma_pm=0.3, sigma_c2c=0.05,
+                ref_mean=0.1, ref_std=0.1)
+SEEDED_W = dict(dw_min=1e-3, sigma_d2d=0.1, sigma_pm=0.3, sigma_c2c=0.05)
+
+
+def _operand_path(w, dw, dev_seed, key, dev, bl):
+    """The parent path of a seeded device: ``sample_device(method='hash')``
+    and fastrng noise drawn over the whole array, then the jnp oracle."""
+    from repro.core import device
+    from repro.kernels import fastrng
+
+    dp = device.sample_device(jax.random.wrap_key_data(dev_seed), w.shape,
+                              dev, method="hash")
+    seed = fastrng.seed_from_key(key)
+    return ref.analog_update_ref(
+        w, dw, dp["gamma"], dp["rho"], fastrng.hash_bits(seed, w.shape, 1),
+        fastrng.hash_normal(seed, w.shape, 2), dw_min=dev.dw_min,
+        tau_min=dev.tau_min, tau_max=dev.tau_max, sigma_c2c=dev.sigma_c2c,
+        bl=bl)
+
+
+@pytest.mark.parametrize("shape,vmapped", [
+    ((64, 384), False),        # 2-D, blocks divide it
+    ((600, 700), False),       # ragged: edge blocks past both bounds
+    ((3, 40, 200), False),     # 3-D tile (scanned layers)
+    ((4, 96, 256), True),      # vmapped over a group of tiles
+    ((2, 3, 33, 97), True),    # vmapped 3-D tiles, ragged
+])
+@pytest.mark.parametrize("dev_kw", [SEEDED_P, SEEDED_W], ids=["P", "W"])
+@pytest.mark.parametrize("bl", [0, 3])
+def test_seeded_kernel_bit_identical_to_operand_path(shape, vmapped, dev_kw,
+                                                     bl):
+    """The seeded kernel (interpreted) draws gamma, rho and the noise at
+    each element's linear index: bit for bit what the operand path draws
+    over the whole array and feeds the oracle, for the same seeds."""
+    from repro.core.device import DeviceConfig
+    from repro.kernels import ops
+
+    dev = DeviceConfig(**dev_kw)
+    ks = jax.random.split(KEY, 4)
+    n = shape[0] if vmapped else 1
+    w = jax.random.uniform(ks[0], shape, jnp.float32, -0.8, 0.8
+                           ).astype(jnp.bfloat16)
+    dw = 3.0 * dev.dw_min * jax.random.normal(ks[1], shape)
+    dev_seed = jax.random.key_data(jax.random.split(ks[2], n)
+                                   ).astype(jnp.uint32)
+    keys = jax.random.key_data(jax.random.split(ks[3], n))
+    if not vmapped:
+        dev_seed, keys = dev_seed[0], keys[0]
+
+    def seeded(w, dw, s, k):
+        return ops.analog_update_seeded(w, dw, s, jax.random.wrap_key_data(k),
+                                        device=dev, bl=bl)
+
+    def operands(w, dw, s, k):
+        return _operand_path(w, dw, s, jax.random.wrap_key_data(k), dev, bl)
+
+    lift = jax.vmap if vmapped else (lambda f: f)
+    want = jax.jit(lift(operands))(w, dw, dev_seed, keys)
+    ops.set_backend("pallas")
+    try:
+        got = jax.jit(lift(seeded))(w, dw, dev_seed, keys)
+    finally:
+        ops.set_backend(None)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert np.count_nonzero(np.asarray(got != w)) > 0.1 * w.size
+
+
+def test_seeded_ref_backend_is_the_operand_path():
+    """Off the Pallas backend ``analog_update_seeded`` is the operand path
+    itself: the device drawn over the array, then ``analog_update``."""
+    from repro.core.device import DeviceConfig
+    from repro.kernels import ops
+
+    dev = DeviceConfig(**SEEDED_P)
+    shape = (5, 33, 97)
+    w = jax.random.uniform(KEY, shape, jnp.float32, -0.8, 0.8)
+    dw = 3e-3 * jax.random.normal(KEY, shape)
+    seed = jnp.array([3, 4], jnp.uint32)
+    got = jax.jit(lambda *a: ops.analog_update_seeded(*a, device=dev))(
+        w, dw, seed, KEY)
+    want = jax.jit(lambda *a: _operand_path(*a, dev, 0))(w, dw, seed, KEY)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_u32_to_f32_is_the_rounded_conversion():
+    """The two-halves uint32 -> f32 conversion equals ``astype(float32)``
+    (round to nearest even) on the edges of the rounding and a sweep."""
+    from repro.kernels import fastrng
+
+    edges = [0, 1, 2 ** 24 - 1, 2 ** 24, 2 ** 24 + 1, 2 ** 24 + 3,
+             2 ** 31 - 1, 2 ** 31, 2 ** 31 + 128, 2 ** 31 + 129,
+             2 ** 31 + 384, 2 ** 32 - 129, 2 ** 32 - 128, 2 ** 32 - 1]
+    rng = np.random.default_rng(1)
+    bits = np.concatenate([np.array(edges, np.uint32),
+                           rng.integers(0, 2 ** 32, 1 << 20, np.uint32)])
+    got = np.asarray(jax.jit(fastrng.u32_to_f32)(jnp.asarray(bits)))
+    np.testing.assert_array_equal(got, bits.astype(np.float32))

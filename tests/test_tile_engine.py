@@ -511,3 +511,49 @@ def test_state_shardings_grouped_smoke():
     sh = state_shardings(state, make_host_mesh(1, 1))
     n_specs = len(jax.tree.leaves(sh))
     assert n_specs == len(jax.tree.leaves(state))
+
+
+def test_seeded_kernel_train_step_bit_identical_to_operand_path():
+    """One E-RIDER step of the qwen2 smoke model with the full-width tile
+    configuration (seeded devices, hash noise, bf16 state, every metric):
+    the Pallas backend, whose seeded kernel draws devices and noise in
+    VMEM, gives the jnp path's losses, P, W and ``tile/*`` bit for bit."""
+    import dataclasses
+
+    from repro import api
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.launch.train import make_tile_cfg
+    from repro.models.lm import LM
+
+    model = LM(get_config("qwen2-0.5b", smoke=True))
+    plan = api.plan_from_spec("erider", lambda a: make_tile_cfg(a, smoke=False))
+    tcfg = plan.policy_for("blocks/l0/mlp/wi").tile
+    assert not tcfg.store_device and tcfg.rng == "hash"
+    tr = AnalogTrainer(model.loss, TrainerConfig(
+        digital=DigitalOptConfig(kind="sgdm", clip_norm=1.0)), plan=plan)
+    toks = jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0, 512)
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
+
+    def run(backend):
+        ops.set_backend(backend)
+        try:
+            state = tr.init(jax.random.PRNGKey(1),
+                            model.init(jax.random.PRNGKey(0)))
+            return tr.jit_step(donate=False)(state, batch)
+        finally:
+            ops.set_backend(None)
+
+    (s_p, m_p), (s_r, m_r) = run("pallas"), run("ref")
+    assert set(m_p) == set(m_r) and any(k.startswith("tile/") for k in m_p)
+    for k in m_p:
+        np.testing.assert_array_equal(np.asarray(m_p[k]), np.asarray(m_r[k]),
+                                      err_msg=k)
+    for c in s_r["tiles"].classes:
+        for arr in ("P", "W"):
+            a, b = (np.asarray(s["tiles"].classes[c][arr], np.float32)
+                    for s in (s_p, s_r))
+            np.testing.assert_array_equal(a, b, err_msg=f"{c}/{arr}")
+    moved = [c for c in s_r["tiles"].classes
+             if np.any(np.asarray(s_r["tiles"].classes[c]["P"]) != 0)]
+    assert moved

@@ -126,3 +126,121 @@ def test_kernel_is_named_in_the_compiled_program(one_chip, pallas_backend):
     assert re.search(rf'op_name="[^"]*/pulse_kernel/[^"]*/{KERNEL_NAME}/',
                      kernels[0])
     assert "/pulse_operands/" in text
+
+
+# minicpm3-4b: the untied 2560 x 73448 head, which no lane block divides,
+# and the 32-wide decoupled-RoPE key projection, narrower than a lane tile
+HEAD = (2560, 73448)
+ROPE_K = (4, 2560, 32)
+P_DEV = dict(kind="softbounds", dw_min=1e-4, sigma_d2d=0.1, sigma_pm=0.3,
+             sigma_c2c=0.05, ref_mean=0.1, ref_std=0.1)
+
+
+def _seeded_text(shape, one_chip, vmapped=False):
+    """Optimized HLO of ``ops.analog_update_seeded`` at ``shape`` (under
+    ``jax.vmap`` over the leading axis when ``vmapped``)."""
+    from repro.core.device import DeviceConfig
+
+    dev = DeviceConfig(**P_DEV)
+    w, dw = (jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+             for dt in (jnp.bfloat16, jnp.float32))
+    n_seeds = (shape[0], 2) if vmapped else (2,)
+    seed, key = (jax.ShapeDtypeStruct(n_seeds, jnp.uint32, sharding=one_chip)
+                 for _ in range(2))
+
+    def update(w, dw, seed, kr):
+        return ops.analog_update_seeded(w, dw, seed,
+                                        jax.random.wrap_key_data(kr),
+                                        device=dev)
+
+    fn = jax.vmap(update) if vmapped else update
+    return _compiled_text(fn, (w, dw, seed, key))
+
+
+@pytest.mark.parametrize("shape,vmapped", [
+    ((896, 4864), False), ((4864, 896), False), (MLP_IN, False),
+    (MLP_IN, True), (HEAD, False), ((1,) + HEAD, True), (ROPE_K, True)])
+def test_seeded_kernel_compiles_for_v5e(one_chip, pallas_backend, shape,
+                                        vmapped):
+    """The seeded kernel in its 2-D, 3-D and vmapped forms at qwen2-0.5b's
+    member shapes and minicpm3-4b's ragged head and narrow RoPE key
+    projection: one Mosaic kernel named
+    ``analog_pulse_update_seeded`` that reads w, dw and the seeds only,
+    with no padding of either."""
+    from repro.kernels.analog_update import SEEDED_KERNEL_NAME
+
+    text = _seeded_text(shape, one_chip, vmapped)
+    calls = _kernel_calls(text)
+    assert [name for name, _ in calls] == [SEEDED_KERNEL_NAME]
+    assert sorted(dt for dt, _ in calls[0][1]) == ["bf16", "f32", "u32"]
+    assert not re.search(r"= (bf16|f32)\[[^\]]*\]\S* pad\(", text)
+
+
+def _kernel_calls(text):
+    """[(kernel name, [(operand dtype, operand dims)])] of every Mosaic
+    kernel in an optimized HLO text."""
+    out = []
+    for ln in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in ln:
+            continue
+        name = re.match(r"\s*(?:ROOT )?%([\w-]+)\.\d+ = ", ln).group(1)
+        args = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=", ln)
+        out.append((name, re.findall(r"(\w+)\[([\d,]*)\]", args.group(1))))
+    return out
+
+
+def _train_step_text(one_chip, store_device, arch="qwen2-0.5b"):
+    """Optimized HLO of the grouped E-RIDER train step of ``arch`` at full
+    width and 2 layers, with the benchmark's tile configuration, for one
+    described v5e."""
+    import dataclasses
+
+    from repro import api
+    from repro.configs import get_config
+    from repro.core.digital_opt import DigitalOptConfig
+    from repro.core.trainer import AnalogTrainer, TrainerConfig
+    from repro.launch.train import make_tile_cfg
+    from repro.models.lm import LM
+
+    model = LM(dataclasses.replace(get_config(arch), n_layers=2,
+                                   n_periods=2))
+    plan = api.plan_from_spec("erider", lambda a: dataclasses.replace(
+        make_tile_cfg(a, smoke=False), store_device=store_device))
+    trainer = AnalogTrainer(model.loss, TrainerConfig(
+        digital=DigitalOptConfig(kind="sgdm", clip_norm=1.0)), plan=plan)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(trainer.init, jax.random.PRNGKey(1), params)
+    on_chip = lambda t: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), t)
+    batch = {k: jax.ShapeDtypeStruct((2, 128), jnp.int32)
+             for k in ("tokens", "labels")}
+    return trainer.jit_step().lower(on_chip(state), on_chip(batch)).compile(
+    ).as_text()
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "minicpm3-4b"])
+def test_train_step_draws_devices_and_noise_in_the_kernel(one_chip,
+                                                           pallas_backend,
+                                                           arch):
+    """With seeded devices (the benchmark's ``store_device: false``, hash
+    RNG) every pulse update of the step is the seeded kernel, and each
+    reads w and its f32 increment only: no weight-sized f32 or u32 device
+    parameter or noise array is written for it."""
+    from repro.kernels.analog_update import SEEDED_KERNEL_NAME
+
+    calls = _kernel_calls(_train_step_text(one_chip, False, arch))
+    assert calls and {name for name, _ in calls} == {SEEDED_KERNEL_NAME}
+    for _, operands in calls:
+        (seeds, sdims), (w, wdims), (dw, dwdims) = operands
+        assert (seeds, w, dw) == ("u32", "bf16", "f32"), operands
+        assert sdims.endswith("1,4") and wdims == dwdims, operands
+
+
+def test_train_step_with_stored_devices_keeps_the_operand_kernel(
+        one_chip, pallas_backend):
+    """Stored device arrays are not drawn from a seed: the operand kernel
+    ``analog_pulse_update`` still runs the step's pulse updates."""
+    from repro.kernels.analog_update import KERNEL_NAME
+
+    calls = _kernel_calls(_train_step_text(one_chip, store_device=True))
+    assert calls and {name for name, _ in calls} == {KERNEL_NAME}
