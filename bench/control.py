@@ -20,12 +20,12 @@ and ``correct``:
 
 The fault "a step that returns its state unchanged" needs no run: its
 change reads 1 by construction. Not run by the benchmark's own runs; needs
-the cell's chip, where it runs at the cell's own size.
+the cell's chips, where it runs at the cell's own size, spread over them as
+a run's reference is.
 """
 from __future__ import annotations
 
 import argparse
-import functools
 import json
 import os
 import sys
@@ -36,27 +36,19 @@ VARIANTS = {"twin": {"stream": 1}, "fp8": {"mode": "fp8"},
 
 
 def readings(config: dict, traffic: dict, seed: int, variants,
-             limits: dict) -> dict:
-    import jax
-
+             limits: dict, ref, mesh=None) -> dict:
+    """The numbers of each variant against the reference, on one device
+    or spread over ``mesh``; ``ref`` is the model module the configuration
+    names."""
     from bench.drivers import train
-    from bench.reference import erider, lm
-    from bench.traffic import bigram
 
-    m, B, S = config["model"], traffic["batch"], traffic["seq"]
-    data = bigram.batches(seed, m["vocab"], train.RING, B, S)
-    ring = [{k: jax.device_put(v[i]) for k, v in data.items()}
-            for i in range(train.CHECKED_STEPS)]
-    kw = {"rows": train.reference_rows(traffic), "remat": True}
+    ring = train.batches(traffic, seed, config["model"]["vocab"],
+                         mesh)[:train.CHECKED_STEPS]
 
     def three(**extra):
-        w0 = lm.leaf_paths(jax.jit(functools.partial(lm.init_params, m))(
-            lm.seed_key(seed)))
-        ref = erider.Reference(m, config["tile"], traffic["recipe"], w0, seed,
-                               **kw, **extra)
-        del w0
-        steps = [ref.step(b) for b in ring]
-        return steps, ref.change(), ref.w_change(), set(ref.digital)
+        plain = train.reference(config, traffic, seed, ref, mesh, **extra)
+        steps = [plain.step(b) for b in ring]
+        return steps, plain.change(), plain.w_change(), set(plain.digital)
 
     ref_steps, ref_change, ref_w, digital = three()
     out = {"w_moved": ref_w["moved"]}
@@ -84,13 +76,14 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
     from bench import run
+    from bench.drivers import train
 
     s = run.spec(args.workload)
     run.enable_compile_cache()
-    run.devices(s["cell"]["chips"])
+    mesh = train.cell_mesh(s["config"], run.devices(s["cell"]["chips"]))
     for seed in (int(x) for x in args.seeds.split(",")):
         r = readings(s["config"], s["traffic"], seed, args.variants.split(","),
-                     s["limits"])
+                     s["limits"], s["reference"], mesh)
         print(json.dumps({"workload": args.workload, "seed": seed, **r}), flush=True)
     return 0
 

@@ -1,6 +1,13 @@
 """Training cells: the program's jitted, donated ``AnalogTrainer.train_step``
 as ``launch/train.py`` builds it, fed from a ring of seeded device batches.
 
+A configuration that states ``"mesh": {"data": D, "model": M}`` runs on a
+(data D, model M) mesh of the cell's D x M chips, laid out as
+``launch/train.py`` lays it out: the program's sharding rules, the trainer
+given the mesh, the state placed by ``state_shardings`` (tile stacks ZeRO-
+split over data), batches by ``batch_shardings``, and the step compiled
+for exactly that layout. Without ``mesh`` a cell runs on one chip.
+
 Set-up builds the step and its state once, runs the first three steps
 through the same call and feed as the window, and keeps what the check
 needs of them: each step's loss, what each leaf's optimizer received in
@@ -8,7 +15,9 @@ step 1 (digital: the momentum buffer; analog: P, which starts at zero), the
 step's expected pulse count, and the weights after step 3. The window then
 continues that same state. Once the window has closed and the peak memory
 has been read, the program's state is freed and the plain reference
-(``bench/reference``) repeats the three steps from the same seed.
+(``bench/reference/erider.py`` over the model module the configuration's
+``reference`` names) repeats the three steps from the same seed, its
+arrays spread over the cell's devices.
 """
 from __future__ import annotations
 
@@ -25,9 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import counts
+from .. import counts, scopes
 from .. import trace as tr
-from ..reference import erider, lm
+from ..reference import erider
 from ..traffic import bigram
 
 RING = 16          # distinct batches the feed cycles through
@@ -58,9 +67,9 @@ def _norm(x):
     return jnp.sqrt(jnp.sum(x * x))
 
 
-def _program_config(config: dict):
+def _program_config(config: dict, ref):
     """The program's ModelConfig: its registry entry with every size of the
-    configuration file applied."""
+    configuration file applied, its layers of the kinds ``ref`` builds."""
     from repro.configs import get_config
     from repro.configs.base import ModelConfig
 
@@ -70,8 +79,7 @@ def _program_config(config: dict):
                               **{k: v for k, v in m.items() if k in fields})
     if jnp.dtype(cfg.dtype).name != m["dtype"]:
         raise ValueError(f"program dtype {cfg.dtype} is not the stated {m['dtype']}")
-    kinds = {"gqa": ("attn",), "mla": ("mla",)}[m["attention"]]
-    if cfg.pattern != kinds or cfg.n_periods != m["n_layers"] or cfg.tail:
+    if cfg.layer_kinds != ref.layer_kinds(m):
         raise ValueError("layer pattern differs from the configuration")
     return cfg
 
@@ -93,24 +101,58 @@ def _check_tile_config(tc, stated: dict) -> None:
         raise ValueError(f"tile configuration differs from the stated one: {bad}")
 
 
-class Cell:
-    """The program's step and state for one training cell."""
+def cell_mesh(config: dict, devs):
+    """The (data, model) mesh the configuration states over the cell's
+    devices, with the program's sharding rules installed, as
+    ``launch/train.py`` sets them; None for one chip."""
+    shape = config.get("mesh")
+    if not shape:
+        return None
+    from repro.distributed import sharding
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.common import set_shard_rules
 
-    def __init__(self, config: dict, traffic: dict, seed: int):
+    mesh = make_host_mesh(shape["data"], shape["model"])
+    if set(mesh.devices.flat) != set(devs):
+        raise ValueError(f"mesh {mesh.devices.tolist()} is not the cell's devices {devs}")
+    set_shard_rules(sharding.logical_rules(mesh))
+    return mesh
+
+
+def batches(traffic: dict, seed: int, vocab: int, mesh=None) -> list:
+    """The ring of seeded batches on the device, or placed over the mesh by
+    the program's ``batch_shardings``."""
+    data = bigram.batches(seed, vocab, RING, traffic["batch"], traffic["seq"])
+    sh = dict.fromkeys(data)  # one chip: the default device
+    if mesh is not None:
+        from repro.distributed import sharding
+
+        sh = sharding.batch_shardings({k: v[0] for k, v in data.items()}, mesh)
+    return [{k: jax.device_put(v[i], sh[k]) for k, v in data.items()}
+            for i in range(RING)]
+
+
+class Cell:
+    """The program's step and state for one training cell, on one chip or
+    laid out over ``mesh``."""
+
+    def __init__(self, config: dict, traffic: dict, seed: int, ref, mesh=None):
         from repro.core.digital_opt import DigitalOptConfig, ScheduleConfig
         from repro.core.trainer import AnalogTrainer, TrainerConfig
+        from repro.distributed import sharding
         from repro.launch.train import make_plan
         from repro.models.lm import LM
 
         self.m = config["model"]
         r = traffic["recipe"]
         t = time.perf_counter()
-        model = LM(_program_config(config))
+        model = LM(_program_config(config, ref))
         plan = make_plan(config["algorithm"], smoke=False)
-        probe = next(p for p in lm.leaf_paths(
-            lm.param_shapes(self.m), is_leaf=lambda x: isinstance(x, tuple))
-            if p.endswith("mlp/wi"))
-        _check_tile_config(plan.policy_for(probe).tile, config["tile"])
+        shapes = ref.leaf_paths(ref.param_shapes(self.m),
+                                is_leaf=lambda x: isinstance(x, tuple))
+        for p, s in shapes.items():
+            if not ref.is_digital(p, len(s)):
+                _check_tile_config(plan.policy_for(p).tile, config["tile"])
         self.trainer = AnalogTrainer(model.loss, TrainerConfig(
             digital=DigitalOptConfig(kind="sgdm", momentum=r["momentum"],
                                      clip_norm=r["clip_norm"]),
@@ -118,11 +160,24 @@ class Cell:
                                     warmup_steps=r["warmup_steps"],
                                     total_steps=r["total_steps"],
                                     min_ratio=r["min_ratio"]),
-            microbatch=traffic["microbatch"]), plan=plan)
+            microbatch=traffic["microbatch"]), plan=plan, mesh=mesh)
         self.timing = {"program_s": time.perf_counter() - t}
 
         t = time.perf_counter()
-        w0 = jax.jit(functools.partial(lm.init_params, self.m))(lm.seed_key(seed))
+        init_w, key_w = functools.partial(ref.init_params, self.m), ref.seed_key(seed)
+        key = jax.random.fold_in(jax.random.PRNGKey(seed % 2**32), seed >> 32)
+        # on a mesh every array is made where it lives (weights by the
+        # program's parameter rules, state by its ZeRO rules, batches by its
+        # batch rules) and the step is compiled for that layout; on one chip
+        # the calls take no layout
+        w_at, state_at, step_at = {}, {}, {}
+        if mesh is not None:
+            aw = jax.eval_shape(init_w, key_w)
+            state_sh = sharding.state_shardings(
+                jax.eval_shape(self.trainer.init, key, aw), mesh)
+            w_at = {"out_shardings": sharding.params_shardings(aw, mesh)}
+            state_at = {"out_shardings": state_sh}
+        w0 = jax.jit(init_w, **w_at)(key_w)
         want = {p: (tuple(s.shape), jnp.dtype(s.dtype).name)
                 for p, s in _flat(jax.eval_shape(model.init,
                                                  jax.random.PRNGKey(0))).items()}
@@ -130,19 +185,20 @@ class Cell:
         if want != have:
             raise ValueError(f"seeded weights do not match the program's "
                              f"parameter layout: {sorted(set(want.items()) ^ set(have.items()))}")
-        key = jax.random.fold_in(jax.random.PRNGKey(seed % 2**32), seed >> 32)
-        self.state = jax.jit(self.trainer.init, donate_argnums=1)(key, w0)
+        self.state = jax.jit(self.trainer.init, donate_argnums=1, **state_at)(key, w0)
         del w0
         jax.block_until_ready(self.state)
         self.timing["init_s"] = time.perf_counter() - t
         t = time.perf_counter()
-        B, S = traffic["batch"], traffic["seq"]
-        data = bigram.batches(seed, self.m["vocab"], RING, B, S)
-        self.ring = [{k: jax.device_put(v[i]) for k, v in data.items()}
-                     for i in range(RING)]
+        self.ring = batches(traffic, seed, self.m["vocab"], mesh)
         self.timing["batches_s"] = time.perf_counter() - t
         t = time.perf_counter()
-        self.step = self.trainer.jit_step().lower(self.state, self.ring[0]).compile()
+        if mesh is not None:
+            step_at = {"in_shardings": (state_sh, jax.tree.map(
+                lambda a: a.sharding, self.ring[0])),
+                       "out_shardings": (state_sh, None)}
+        self.step = self.trainer.jit_step(**step_at).lower(
+            self.state, self.ring[0]).compile()
         self.timing["compile_s"] = time.perf_counter() - t
         self.n = 0
 
@@ -265,15 +321,89 @@ def reference_rows(traffic: dict) -> int:
     return max(1, min(traffic["batch"], 2048 // traffic["seq"]))
 
 
+def _spread(mesh, shape):
+    """A sharding over every device of ``mesh``: the last dimension that
+    divides among them is split, an array with none is replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    for d in reversed(range(len(shape))):
+        if shape[d] % mesh.size == 0:
+            spec = [None] * len(shape)
+            spec[d] = tuple(mesh.axis_names)
+            return NamedSharding(mesh, PartitionSpec(*spec))
+    return NamedSharding(mesh, PartitionSpec())
+
+
+def reference(config: dict, traffic: dict, seed: int, ref, mesh=None,
+              **variant) -> erider.Reference:
+    """The plain reference of a training cell from ``seed``: the weights of
+    ``ref`` (the model module the configuration names) made on the device,
+    or spread over the devices of ``mesh`` and with them every array of the
+    reference's state; ``variant`` as ``erider.Reference`` takes it."""
+    init, key = functools.partial(ref.init_params, config["model"]), ref.seed_key(seed)
+    placed = {} if mesh is None else {"out_shardings": jax.tree.map(
+        lambda a: _spread(mesh, a.shape), jax.eval_shape(init, key))}
+    w0 = ref.leaf_paths(jax.jit(init, **placed)(key))
+    return erider.Reference(config["model"], config["tile"], traffic["recipe"],
+                            w0, seed, ref=ref, rows=reference_rows(traffic),
+                            remat=True, **variant)
+
+
+def _without_exchange(step, mesh):
+    """``step`` with the data axis's exchange left out (a planted fault):
+    each data replica steps the state on its own rows alone and keeps what
+    it holds of the result, so its ZeRO shard is updated from its local
+    gradient and its copy of a replicated leaf drifts from the others'. The
+    metrics are the replicas' mean: step 1's loss stays the batch's."""
+    D = mesh.shape["data"]
+    replica = {d: i for i, row in enumerate(mesh.devices) for d in row.flat}
+
+    take = jax.jit(lambda b, i: jax.tree.map(
+        lambda x: jnp.concatenate([jnp.split(x, D)[i]] * D), b), static_argnums=1)
+
+    def own_rows(batch, i):  # replica i's rows in every replica's place
+        return jax.device_put(take(batch, i),
+                              jax.tree.map(lambda a: a.sharding, batch))
+
+    def held(*xs):
+        shards = [{s.device: s.data for s in x.addressable_shards} for x in xs]
+        return jax.make_array_from_single_device_arrays(
+            xs[0].shape, xs[0].sharding,
+            [shards[replica[d]][d] for d in shards[0]])
+
+    def faulty(state, batch):
+        outs = [step(jax.tree.map(jnp.copy, state), own_rows(batch, i))
+                for i in range(D)]
+        return (jax.tree.map(held, *[s for s, _ in outs]),
+                jax.tree.map(lambda *v: sum(v) / D, *[m for _, m in outs]))
+
+    return faulty
+
+
+def peak_bytes(devs) -> int:
+    """``peak_bytes_in_use`` of the fullest of ``devs``; 0 where the
+    backend keeps no such count."""
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devs)
+
+
 def run(config: dict, traffic: dict, seed: int, seconds: float, trace: bool,
-        limits: dict, fault: str | None = None, t0: float | None = None) -> dict:
-    """One run of a training cell; set-up is counted from ``t0`` (the
-    process's start), or from here. ``fault`` plants a fault in the timed
-    path for the harness's own tests: "frozen" (the step returns its state
-    unchanged) or "half_batch" (the step sees half of each batch)."""
+        limits: dict, *, ref, devs, fault: str | None = None,
+        t0: float | None = None) -> dict:
+    """One run of a training cell on ``devs`` (one chip, or the mesh the
+    configuration states), checked against ``ref``, the model module the
+    configuration names; set-up is counted from ``t0`` (the process's
+    start), or from here. ``fault`` plants a fault in the timed path for
+    the harness's own tests: "frozen" (the step returns its state
+    unchanged), "half_batch" (the step sees half of each batch) or, on a
+    mesh, "no_exchange" (``_without_exchange``)."""
     t0 = time.perf_counter() if t0 is None else t0
-    cell = Cell(config, traffic, seed)
-    if fault == "frozen":
+    mesh = cell_mesh(config, devs)
+    if fault == "no_exchange" and mesh is None:
+        raise ValueError("no_exchange needs a mesh: one chip exchanges nothing")
+    cell = Cell(config, traffic, seed, ref, mesh)
+    if fault == "no_exchange":
+        cell.step = _without_exchange(cell.step, mesh)
+    elif fault == "frozen":
         step = cell.step
         cell.step = lambda s, b: (s, step(jax.tree.map(jnp.copy, s), b)[1])
     elif fault == "half_batch":
@@ -329,23 +459,19 @@ def run(config: dict, traffic: dict, seed: int, seconds: float, trace: bool,
         red = tr.reduce(tr.load(tr.find(tdir)))
         shutil.rmtree(tdir, ignore_errors=True)
         e2e = None
-    dev = jax.devices()[0]
-    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    peak = peak_bytes(devs)
+    # the compiled step's instruction -> scope names, for the scope readers
+    op_scopes = scopes.op_scopes(cell.step.as_text()) if trace else None
 
     # the check: free the program, run the reference from the same seed
     ring, cell_timing = cell.ring, cell.timing
     del cell, met
     gc.collect()
     t_ref = time.perf_counter()
-    w0 = lm.leaf_paths(jax.jit(functools.partial(lm.init_params, config["model"]))(
-        lm.seed_key(seed)))
-    ref = erider.Reference(config["model"], config["tile"], traffic["recipe"],
-                           w0, seed, rows=reference_rows(traffic),
-                           remat=True)
-    del w0
-    ref_steps = [ref.step(ring[k]) for k in range(CHECKED_STEPS)]
-    w_moved = {"program": _program_w_change(start, end), "reference": ref.w_change()}
-    nums = compare(prog, ref_steps, ref.change(), _program_change(start, end),
+    plain = reference(config, traffic, seed, ref, mesh)
+    ref_steps = [plain.step(ring[k]) for k in range(CHECKED_STEPS)]
+    w_moved = {"program": _program_w_change(start, end), "reference": plain.w_change()}
+    nums = compare(prog, ref_steps, plain.change(), _program_change(start, end),
                    w_moved["reference"], w_moved["program"])
     del start, end
     timing = {"setup_s": setup_s, **cell_timing, "check_copies_s": aside,
@@ -355,9 +481,11 @@ def run(config: dict, traffic: dict, seed: int, seconds: float, trace: bool,
     if trace:
         m = config["model"]
         rec = {"trace": red, "steps": steps, "tokens": steps * B * S,
-               "flops_per_token": counts.flops_per_token(m, S),
-               "update_bytes_per_step": counts.update_bytes_per_step(m, config["tile"]),
-               "peak_bytes": peak}
+               "chips": len(devs),
+               "flops_per_token": counts.flops_per_token(m, S, ref),
+               "update_bytes_per_step": counts.update_bytes_per_step(
+                   m, config["tile"], ref),
+               "peak_bytes": peak, "op_scopes": op_scopes}
     return {"correct": correct, "attempted": steps, "failed": failed,
             "e2e": e2e, "record": rec, "peak_bytes": peak, "checks": checks,
             "left_out": sorted(set(ref_steps[0]["grad_norm"])

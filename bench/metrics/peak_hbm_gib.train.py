@@ -1,5 +1,5 @@
 """Peak device memory of the run, ``memory_stats()["peak_bytes_in_use"]``
-read after the window, in GiB."""
+read after the window, in GiB: on several chips, the fullest chip's."""
 
 
 def read(rec):

@@ -1,6 +1,7 @@
-"""Whole training step's share of the chip's bf16 peak: model FLOPs per
+"""Whole training step's share of the chips' bf16 peak: model FLOPs per
 token (``bench/counts.py``) times the tokens trained in the traced window,
-over the window's seconds and the peak of ``bench/peaks.json``."""
+over the window's seconds and the peak of ``bench/peaks.json`` times the
+chips the cell runs on."""
 
 
 def read(rec):
@@ -8,4 +9,4 @@ def read(rec):
     if not red or not rec.get("tokens"):
         return None
     rate = rec["flops_per_token"] * rec["tokens"] / red["window_s"]
-    return 100.0 * rate / rec["peaks"]["bf16_flops_per_s"]
+    return 100.0 * rate / (rec.get("chips", 1) * rec["peaks"]["bf16_flops_per_s"])

@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import lm
-
 
 def _fold(key, *names):
     for n in names:
@@ -115,7 +113,11 @@ def _norm(x):
 class Reference:
     """Three steps of E-RIDER training from seeded weights ``w0`` (a
     {path: array} dict in the served type) on ``batches`` (a list of
-    {tokens, labels} device arrays).
+    {tokens, labels} device arrays), over the model of ``ref``, the plain
+    reference module the configuration names (``bench/reference/lm.py``):
+    its loss, its parameter layout and what it keeps digital. Every array
+    of the state is placed as its leaf of ``w0`` is, so weights spread over
+    several devices keep the whole reference spread over them.
 
     ``mode`` is the matmul arithmetic ("f32", or "fp8" for the control);
     ``rows`` the batch rows per gradient block (blocks keep the float32
@@ -123,7 +125,7 @@ class Reference:
     batch and ``transfer=False`` the transfer of P into W, (18b) (planted
     faults, for the control runs)."""
 
-    def __init__(self, m, tile, recipe, w0, seed, *, mode="f32", rows=8,
+    def __init__(self, m, tile, recipe, w0, seed, *, ref, mode="f32", rows=8,
                  remat=False, drop_half=False, transfer=True, stream=0):
         self.m, self.t, self.r = m, tile, recipe
         self.mode, self.rows, self.drop_half = mode, rows, drop_half
@@ -131,7 +133,7 @@ class Reference:
                          "reference", seed >> 32, mode, drop_half, stream,
                          *(() if transfer else ("no_transfer",)))
         self.analog = sorted(p for p, a in w0.items()
-                             if not lm.is_digital(p, a.ndim))
+                             if not ref.is_digital(p, a.ndim))
         self.digital = sorted(p for p in w0 if p not in self.analog)
         tau = min(tile["device_w"]["tau_min"], tile["device_w"]["tau_max"])
         sdt = jnp.dtype(tile["state_dtype"])
@@ -140,19 +142,19 @@ class Reference:
             w = w0[p].astype(jnp.float32)
             s = jnp.maximum(jnp.max(jnp.abs(w)), tile["min_weight_range"]) / (
                 tile["target_range"] * tau)
-            z = jnp.zeros(w.shape, sdt)
+            z = jnp.zeros_like(w, sdt)
             self.scale[p] = s
             self.tiles[p] = {"W": (w / s).astype(sdt), "P": z, "Qd": z, "Qt": z,
                              "c": jnp.float32(1.0)}
         self.theta = {p: w0[p] for p in self.digital}
-        self.mu = {p: jnp.zeros(w0[p].shape, jnp.float32) for p in self.digital}
+        self.mu = {p: jnp.zeros_like(w0[p], jnp.float32) for p in self.digital}
         # the state every change is measured from
         self.start = {p: self.tiles[p]["W"] for p in self.analog}
         self.start.update(self.theta)
         self.step_no = 0
 
         def lsum(eff, tok, lab):
-            return lm.loss_sum(lm.unflatten(eff), tok, lab, m, mode, remat)
+            return ref.loss_sum(ref.unflatten(eff), tok, lab, m, mode, remat)
 
         self._vg = jax.jit(jax.value_and_grad(lsum))
         self._upd = jax.jit(functools.partial(_tile_update, t=tile,

@@ -38,6 +38,11 @@ DIGITAL_MARKERS = ("embed", "vocab", "lm_head", "pos")
 # ---------------------------------------------------------------------------
 
 
+def layer_kinds(m: dict) -> tuple:
+    """The program's layer kind for each layer, in order."""
+    return ({"gqa": "attn", "mla": "mla"}[m["attention"]],) * m["n_layers"]
+
+
 def layer_shapes(m: dict) -> dict:
     """Shapes of one layer's leaves (before stacking over layers)."""
     d, ff = m["d_model"], m["d_ff"]

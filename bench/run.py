@@ -3,21 +3,27 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell is made of is found by name: its entry in BENCHMARK.json
-names a configuration (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``, whose ``kind`` picks the module that
-runs it, ``bench/drivers/<kind>.py``); its limits are ``bench/limits/<cell>.json``;
-each per-layer metric is read by ``bench/metrics/<metric>.py``. The run
-refuses to start where JAX finds no TPU, or fewer chips than the cell asks
-for. The last line of standard output is one JSON object; the numbers the
-check compared, each beside its limit, end standard error.
+names a configuration (``bench/configs/<config>.json``, whose ``reference``
+is the file of its plain reference model and whose ``mesh``, if any, lays
+the cell over its chips) and a traffic mix (``bench/traffic/<traffic>.json``,
+whose ``kind`` picks the module that runs it, ``bench/drivers/<kind>.py``);
+its limits are ``bench/limits/<cell>.json``; each per-layer metric is read
+by ``bench/metrics/<metric>.py``. The run refuses to start where JAX finds
+no TPU, or fewer chips than the cell asks for, and a cell whose chips are
+not its configuration's mesh is an error. The last line of standard output
+is one JSON object; the numbers the check compared, each beside its limit,
+end standard error.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib
 import importlib.util
 import json
+import math
 import os
+import re
 import sys
 import time
 
@@ -31,20 +37,38 @@ def load_json(*parts):
         return json.load(f)
 
 
+@functools.lru_cache(maxsize=None)
+def module(path: str):
+    """The Python file at ``path``, relative to the checkout's root, loaded
+    as a module of its own."""
+    name = "bench_" + re.sub(r"\W", "_", path)
+    mod_spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
 def spec(name: str) -> dict:
-    """The cell's entry, configuration, traffic mix and limits."""
+    """The cell's entry, configuration, traffic mix, limits and reference
+    module."""
     bench = load_json(ROOT, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r}; known: {sorted(cells)}")
     cell = cells[name]
+    config = load_json(BENCH, "configs", cell["config"] + ".json")
+    chips = math.prod(config.get("mesh", {}).values())
+    if chips != cell["chips"]:
+        raise SystemExit(f"cell {name!r} asks for {cell['chips']} chips, its "
+                         f"configuration's mesh holds {chips}")
     return {
         "benchmark": bench,
         "cell": cell,
-        "config": load_json(BENCH, "configs", cell["config"] + ".json"),
+        "config": config,
         "traffic": load_json(BENCH, "traffic", cell["traffic"] + ".json"),
         "limits": load_json(BENCH, "limits", name + ".json"),
         "peaks": load_json(BENCH, "peaks.json"),
+        "reference": module(config["reference"]),
     }
 
 
@@ -63,11 +87,7 @@ def metrics_for(bench: dict, cell: str, trace: bool) -> list:
 
 def reader(name: str):
     """``read(record) -> float | None`` of ``bench/metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", name + ".py")
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return module(f"bench/metrics/{name}.py").read
 
 
 def enable_compile_cache() -> None:
@@ -108,6 +128,7 @@ def result_line(s: dict, res: dict, devs, trace: bool) -> dict:
             v = res["e2e"].get(m["name"])
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    # memory_peak_bytes: the fullest device's peak
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": res["peak_bytes"]}
     line = {"correct": res["correct"], "attempted": res["attempted"],
@@ -151,7 +172,8 @@ def execute(s: dict, seed: int, seconds: float, trace: bool, devs,
     fault in the timed path (the harness's own tests)."""
     driver = importlib.import_module(f"bench.drivers.{s['traffic']['kind']}")
     res = driver.run(s["config"], s["traffic"], seed, seconds, trace,
-                     s["limits"], fault=fault, t0=T0)
+                     s["limits"], ref=s["reference"], devs=devs, fault=fault,
+                     t0=T0)
     line = result_line(s, res, devs, trace)
     print("seconds: " + ", ".join(f"{k} {v:.3f}" for k, v in res["timing"].items()),
           file=sys.stderr)

@@ -8,11 +8,9 @@ holds the ``jax.named_scope`` path it was traced under. ``op_scopes`` maps
 instruction names to op_names; ``scope_seconds`` sums the operations'
 seconds by the scopes their op_name holds.
 
-A traced run's record carries no HLO, so the readers take ``op_scopes``
-from ``running_cell_scopes``: the step of the cell that the process runs,
-built again as ``bench/drivers/train.py`` builds it (same program, shapes
-and compile call, so the same executable, which JAX's compilation cache
-hands back). A record may carry its own ``op_scopes`` (the tests do).
+A traced run's record carries ``op_scopes`` of the executable it ran
+(``bench/drivers/train.py`` reads the compiled step's ``as_text()`` after
+the window).
 
 A segment of an op_name counts as a scope with its transforms taken off:
 ``jvp(fwd_bwd)``, ``transpose(jvp(fwd_bwd))`` and ``vmap(pulse_kernel)``
@@ -21,10 +19,7 @@ merged from ops of several scopes counts under the first one's path.
 """
 from __future__ import annotations
 
-import argparse
-import gc
 import re
-import sys
 
 from . import trace
 
@@ -171,48 +166,9 @@ def scope_seconds(rec: dict, include, exclude=()) -> float | None:
     return total if hit else None
 
 
-def step_scopes(config: dict, traffic: dict, seed: int) -> dict:
-    """``op_scopes`` of a training cell's compiled step, built by the
-    driver's own ``Cell``; its state is freed before this returns."""
-    from .drivers import train
-
-    cell = train.Cell(config, traffic, seed)
-    text = cell.step.as_text()
-    del cell
-    gc.collect()
-    return op_scopes(text)
-
-
-_RUNNING: dict = {}
-
-
-def running_cell_scopes() -> dict | None:
-    """``op_scopes`` of the step of the training cell this process runs,
-    named by its ``bench/run.py`` arguments (``--workload``, ``--seed``),
-    built once; None in a process that runs no cell or a cell that does not
-    train."""
-    ap = argparse.ArgumentParser(add_help=False, allow_abbrev=False)
-    ap.add_argument("--workload")
-    ap.add_argument("--seed", type=int, default=0)
-    args, _ = ap.parse_known_args(sys.argv[1:])
-    if not args.workload:
-        return None
-    if args.workload not in _RUNNING:
-        from . import run
-
-        s = run.spec(args.workload)
-        _RUNNING[args.workload] = (
-            step_scopes(s["config"], s["traffic"], args.seed)
-            if s["traffic"]["kind"] == "train" else None)
-    return _RUNNING[args.workload]
-
-
 def ms_per_step(rec: dict, include, exclude=()) -> float | None:
-    """``scope_seconds`` in milliseconds per traced step; the record's
-    ``op_scopes``, else those of the running cell."""
+    """``scope_seconds`` in milliseconds per traced step."""
     if not rec.get("trace") or not rec.get("steps"):
         return None
-    if rec.get("op_scopes") is None:
-        rec = dict(rec, op_scopes=running_cell_scopes())
     s = scope_seconds(rec, include, exclude)
     return None if s is None else 1e3 * s / rec["steps"]

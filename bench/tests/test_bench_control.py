@@ -16,7 +16,7 @@ CELLS = ["train.qwen2-0.5b.b8x128", "train.minicpm3-4b-l4.b8x128"]
 def test_control_and_fault_separate_from_the_draws():
     s = tiny_spec()
     r = control.readings(s["config"], s["traffic"], 7, ["twin", "fp8", "half"],
-                         s["limits"])
+                         s["limits"], s["reference"])
     twin, fp8, half = r["twin"], r["fp8"], r["half"]
     assert fp8["loss_gap"] > 100 * twin["loss_gap"]
     assert fp8["pulses1_gap"] > 10 * twin["pulses1_gap"]
@@ -28,7 +28,8 @@ def test_control_and_fault_separate_from_the_draws():
 def _readings(cell):
     s = tiny_spec()
     return control.readings(s["config"], s["traffic"], 2**33 + 9,
-                            ["fp8", "half"], run.spec(cell)["limits"])
+                            ["fp8", "half"], run.spec(cell)["limits"],
+                            s["reference"])
 
 
 @pytest.mark.parametrize("cell", CELLS)

@@ -60,14 +60,14 @@ def test_counts_match_hand_counts_for_qwen2():
                  + 896 * 2                          # ln1 ln2
                  + 896 * 4864 * 3)                  # wi wg wo
     assert per_layer == 14_912_384
-    assert counts.analog_weights(m) == 24 * per_layer == 357_897_216
+    assert counts.analog_weights(m, lm) == 24 * per_layer == 357_897_216
     head = 151936 * 896
-    assert counts.matmul_weights(m) == 24 * 14_909_440 + head == 493_961_216
+    assert counts.matmul_weights(m, lm) == 24 * 14_909_440 + head == 493_961_216
     attn = 3 * 24 * 14 * (64 + 64) * 129
-    assert counts.flops_per_token(m, 128) == 6 * 493_961_216 + attn == 2_980_411_392
+    assert counts.flops_per_token(m, 128, lm) == 6 * 493_961_216 + attn == 2_980_411_392
     tile = _config("qwen2-0.5b")["tile"]
     # P and W pulsed: bf16 read + write, plus a bf16 increment, each
-    assert counts.update_bytes_per_step(m, tile) == 357_897_216 * 2 * 6
+    assert counts.update_bytes_per_step(m, tile, lm) == 357_897_216 * 2 * 6
 
 
 def test_reference_layout_is_the_configuration():

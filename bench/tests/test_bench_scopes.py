@@ -199,7 +199,7 @@ def test_op_scopes_of_a_compiled_cell_hold_every_phase():
     from test_bench_faults import tiny_spec
 
     s = tiny_spec()
-    cell = train.Cell(s["config"], s["traffic"], 2**33 + 5)
+    cell = train.Cell(s["config"], s["traffic"], 2**33 + 5, s["reference"])
     text = cell.step.as_text()
     names = scopes.op_scopes(text)
     found = {g for op in names.values() for g in scopes.segments(op)}
@@ -208,40 +208,40 @@ def test_op_scopes_of_a_compiled_cell_hold_every_phase():
     assert all(f"%{n} = " in text for n in names)
 
 
-def _as_run(monkeypatch, argv):
-    """This process as ``bench/run.py`` with ``argv``, the tiny cell under
-    the name ``tiny``, and no scopes built yet."""
+def test_a_traced_run_reads_the_running_cells_scopes(monkeypatch):
+    """A traced run's record carries the scopes of the step it ran, built
+    from that executable and not again, and the four readers read every
+    phase from them. The CPU has no TPU plane, so the trace's reduction is
+    stood in for: each instruction of the step ran 1 ms."""
+    import jax
+
+    from bench.drivers import train
+
     from test_bench_faults import tiny_spec
 
-    spec = run.spec
-    monkeypatch.setattr(run, "spec",
-                        lambda name: tiny_spec() if name == "tiny" else spec(name))
-    monkeypatch.setattr(scopes, "_RUNNING", {})
-    monkeypatch.setattr("sys.argv", ["bench/run.py", *argv])
+    red = {"ops_s": {}, "window_s": 1.0, "busy_s": 1.0, "device_ops": [],
+           "idle_gaps": []}
+    monkeypatch.setattr(tr, "find", lambda d: d)
+    monkeypatch.setattr(tr, "load", lambda path: None)
+    monkeypatch.setattr(tr, "reduce", lambda t: red)
+    built = []
+    op_scopes = scopes.op_scopes
 
+    def from_the_step(text):
+        names = op_scopes(text)
+        built.append(names)
+        red["ops_s"].update({f"%{n} = f32[] fusion(%x), kind=kLoop": 1e-3
+                             for n in names})
+        return names
 
-def test_a_traced_run_reads_the_running_cells_scopes(monkeypatch):
-    """A record without ``op_scopes`` (the driver keeps none) is read by
-    the scopes of the step that ``--workload`` names, built once."""
-    _as_run(monkeypatch, ["--workload", "tiny", "--seed", str(2**33 + 5),
-                          "--seconds", "1", "--trace", "1"])
-    names = scopes.running_cell_scopes()
-    assert scopes.running_cell_scopes() is names
-    found = {g for op in names.values() for g in scopes.segments(op)}
+    monkeypatch.setattr(scopes, "op_scopes", from_the_step)
+    line = run.execute(tiny_spec(), seed=2**33 + 5, seconds=0.5, trace=True,
+                       devs=jax.devices())
+    assert len(built) == 1 and train.scopes is scopes
+    found = {g for op in built[0].values() for g in scopes.segments(op)}
     assert set(scopes.PHASES) | {"pulse_operands", "pulse_kernel",
                                  "tile_metrics"} <= found
-    ops = {f"%{n} = f32[] fusion(%x), kind=kLoop": 1e-3 for n in names}
-    rec = {"trace": {"ops_s": ops, "window_s": 1.0, "busy_s": 1.0}, "steps": 1}
+    rec = {"trace": red, "steps": 2, "op_scopes": built[0]}
     for name in NEW:
-        got = _reader(name)(rec)
-        assert got is not None and got > 0, name
-        assert got == _reader(name)(dict(rec, op_scopes=names)), name
-
-
-@pytest.mark.parametrize("argv", [[], ["--dist", "loadfile", "--sw"],
-                                  ["--work", "tiny"]])
-def test_no_running_cell_without_a_workload(monkeypatch, argv):
-    _as_run(monkeypatch, argv)
-    assert scopes.running_cell_scopes() is None
-    for name in NEW:
-        assert _reader(name)(_rec(op_scopes=None)) is None
+        got = line["metrics"][name]["value"]
+        assert got > 0 and got == _reader(name)(rec), name
